@@ -25,7 +25,8 @@ def _spmd_time(devices: int, domain, iters: int, bulk_sync: bool) -> float:
         from repro.apps.jacobi3d import make_spmd_step
         from jax.sharding import NamedSharding, PartitionSpec as PS
         import jax.numpy as jnp
-        mesh = jax.make_mesh(({devices},), ('data',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(({devices},), ('data',))
         step = make_spmd_step(mesh, 'data', bulk_sync={bulk_sync})
         rng = np.random.default_rng(0)
         u = jax.device_put(jnp.asarray(rng.random({tuple(domain)},
@@ -38,6 +39,8 @@ def _spmd_time(devices: int, domain, iters: int, bulk_sync: bool) -> float:
         print((time.perf_counter() - t0) / {iters})
     """
     env = dict(os.environ)
+    # a CPU rehearsal on virtual devices: never the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

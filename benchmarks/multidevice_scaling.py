@@ -48,6 +48,8 @@ def _throughput(devices: int, dedicated: bool, n: int = 64,
                                'devices_used': len(used)}}))
     """
     env = dict(os.environ)
+    # a CPU rehearsal on virtual devices: never the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

@@ -7,16 +7,19 @@ Prints ``name,us_per_call,derived`` CSV rows (stdout), mirroring:
   Fig. 13/15  Jacobi3D scaling + over-decomposition (jacobi_scaling)
 plus a summary of the multi-pod dry-run + roofline table (reads the JSONs
 produced by benchmarks/run_dryrun_sweep.py — run that first for fresh data).
+
+Each section runs in a child process of its own and this process never
+imports JAX: a chip belongs to one process at a time, and a section that
+takes it gives it back when its child exits.
 """
 import json
 import glob
 import os
+import subprocess
 import sys
-import traceback
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-sys.path.insert(0, os.path.join(os.path.dirname(HERE), "src"))
-sys.path.insert(0, os.path.dirname(HERE))
+REPO = os.path.dirname(HERE)
 
 
 def _section(title):
@@ -24,24 +27,27 @@ def _section(title):
 
 
 def main() -> None:
-    from benchmarks import (jacobi_scaling, multidevice_scaling, pingpong,
-                            tasking_overhead)
-
     sections = [
-        ("fig8 tasking overhead ladder", tasking_overhead.main),
-        ("fig9 multi-device scaling", multidevice_scaling.main),
-        ("fig10-12 pingpong", pingpong.main),
-        ("fig13/15 jacobi scaling + over-decomposition", jacobi_scaling.main),
+        ("fig8 tasking overhead ladder", "tasking_overhead"),
+        ("fig9 multi-device scaling", "multidevice_scaling"),
+        ("fig10-12 pingpong", "pingpong"),
+        ("fig13/15 jacobi scaling + over-decomposition", "jacobi_scaling"),
     ]
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(REPO, "src"), REPO] +
+        ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     failures = []
-    for title, fn in sections:
+    for title, module in sections:
         _section(title)
-        try:
-            fn()
-        except Exception as e:   # keep the harness running
+        # keep the harness running: a failed section is reported, not fatal
+        rc = subprocess.run(
+            [sys.executable, "-c",
+             f"from benchmarks import {module}; {module}.main()"],
+            env=env, cwd=REPO).returncode
+        if rc != 0:
             failures.append(title)
-            print(f"SECTION_FAILED {title}: {e}", flush=True)
-            traceback.print_exc()
+            print(f"SECTION_FAILED {title}: exit code {rc}", flush=True)
 
     _section("dry-run / roofline summary")
     result_files = sorted(glob.glob(os.path.join(HERE, "results", "dryrun",

@@ -31,7 +31,9 @@ def cells():
 
 def _run_subprocess_cell(tag, cmd, env, meta, timeout):
     """One sweep cell in an isolated subprocess: cached-JSON skip, error
-    recording (``meta`` + the failure), and OK/FAIL/TIME reporting."""
+    recording (``meta`` + the failure), and OK/FAIL/TIME reporting.
+    Every cell is a CPU rehearsal, so the child never asks for the chip."""
+    env = dict(env, JAX_PLATFORMS="cpu")
     out_path = os.path.join(OUT_DIR, tag + ".json")
     if os.path.exists(out_path):
         with open(out_path) as f:

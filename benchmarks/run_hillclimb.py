@@ -53,6 +53,7 @@ def run(arch, shape, variant, probe=None, timeout=3600):
         cmd += ["--probe", str(probe)]
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"         # dry-run lowering on virtual devices
     t0 = time.time()
     proc = subprocess.run(cmd, capture_output=True, text=True,
                           timeout=timeout, env=env, cwd=REPO)

@@ -1,67 +1,2 @@
 """repro — reproduction of "Runtime Support for Performance Portability on
-Heterogeneous Distributed Platforms" on the JAX/XLA stack.
-
-Compatibility: call sites use the modern ``jax.shard_map`` spelling and are
-handed the native implementation whenever this jax provides one. On the
-older jax in this container it only exists under ``jax.experimental`` with
-the old signature, so alias it once here (this package root is imported
-before any ``repro.*`` submodule). The alias also emulates the new
-partial-manual semantics (``axis_names=``): it maps the manual set onto the
-old ``auto=`` complement and records the manual axes in a thread-local while
-the body traces, so ``repro.models.sharding.constrain`` can filter them out
-of inner sharding constraints the way native shard_map does.
-"""
-import functools
-import threading
-
-import jax
-
-#: True when this jax predates the native ``jax.shard_map`` API and the
-#: aliases below are in effect. Code needing partial-manual semantics the
-#: old XLA cannot compile (e.g. the compressed-gradient train step)
-#: branches on this to an equivalent formulation.
-COMPAT_SHARD_MAP = not hasattr(jax, "shard_map")
-
-_compat_manual = threading.local()
-
-
-def compat_manual_axes() -> frozenset:
-    """Mesh axes manual in the shard_map body currently tracing on this
-    thread (compat shim only; empty outside a shard_map trace)."""
-    return getattr(_compat_manual, "axes", frozenset())
-
-
-if COMPAT_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _experimental_sm
-
-    def _shard_map(f, *args, **kwargs):
-        if "check_vma" in kwargs:        # new-API name for check_rep
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        manual = None
-        if "axis_names" in kwargs:       # new API: axes to shard manually;
-            manual = frozenset(kwargs.pop("axis_names"))  # old API wants the
-            mesh = kwargs.get("mesh", args[0] if args else None)  # converse
-            kwargs["auto"] = frozenset(
-                n for n in mesh.axis_names if n not in manual)
-
-        if manual is not None:
-            @functools.wraps(f)
-            def body(*a, **k):
-                prev = compat_manual_axes()
-                _compat_manual.axes = prev | manual
-                try:
-                    return f(*a, **k)
-                finally:
-                    _compat_manual.axes = prev
-        else:
-            body = f
-        return _experimental_sm(body, *args, **kwargs)
-
-    jax.shard_map = _shard_map
-
-if not hasattr(jax.lax, "axis_size"):
-    def _axis_size(axis_name):
-        frame = jax.core.axis_frame(axis_name)
-        return getattr(frame, "size", frame)   # older jax returns the int
-
-    jax.lax.axis_size = _axis_size
+Heterogeneous Distributed Platforms" on the JAX/XLA stack."""

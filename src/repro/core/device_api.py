@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import abc
 import dataclasses
+import os
 import threading
 
 from repro.core import sanitizer
@@ -217,42 +218,25 @@ class JaxDevice(Device):
         return jax.block_until_ready(handle)
 
     def is_ready(self, handle: Any) -> bool:
-        try:
-            leaves = jax.tree.leaves(handle)
-            return all(l.is_ready() for l in leaves
-                       if hasattr(l, "is_ready"))
-        except Exception:
-            return True
-
-
-def _host_memory_bytes() -> Optional[int]:
-    try:
-        with open("/proc/meminfo") as f:
-            for line in f:
-                if line.startswith("MemTotal:"):
-                    return int(line.split()[1]) * 1024
-    except OSError:
-        pass
-    return None
+        return all(l.is_ready() for l in jax.tree.leaves(handle)
+                   if hasattr(l, "is_ready"))
 
 
 def device_capacity(jax_device: jax.Device, n_devices: int,
                     fraction: float = 0.75) -> int:
-    """Honest per-device capacity: ask the backend for its byte limit
-    (GPU/TPU expose one via memory_stats); CPU devices split the host's
-    physical memory. Falls back to the 16 GiB v5e-like default."""
-    try:
-        stats = jax_device.memory_stats()
-        if stats:
-            limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-            if limit:
-                return int(limit * fraction)
-    except Exception:
-        pass
-    host = _host_memory_bytes()
-    if host is not None and n_devices > 0:
+    """Honest per-device capacity: CPU devices split the host's physical
+    memory; an accelerator must report its byte limit through
+    ``memory_stats`` (GPU/TPU do), and one that reports none is refused
+    rather than given a guessed size."""
+    if jax_device.platform == "cpu":
+        host = os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
         return int(host * fraction / n_devices)
-    return int(16 * (1 << 30) * fraction)
+    stats = jax_device.memory_stats() or {}
+    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    if not limit:
+        raise RuntimeError(f"{jax_device} reports no memory limit "
+                           f"(memory_stats: {stats})")
+    return int(limit * fraction)
 
 
 def discover_devices(memory_capacity: Optional[int] = None,

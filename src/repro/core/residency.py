@@ -203,6 +203,12 @@ class ResidencyLedger:
         with self._lock:
             return set(self._where.get(id(obj), ()))
 
+    def objects_on(self, device_id: int) -> list:
+        """Objects holding a valid replica on ``device_id``, least recently
+        used first."""
+        with self._lock:
+            return [e.obj for e in self._lru[device_id].values()]
+
     def holds(self, device_id: int, obj) -> bool:
         with self._lock:
             return id(obj) in self._lru[device_id]

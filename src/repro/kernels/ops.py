@@ -1,14 +1,13 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in the
-CPU container (kernel bodies execute in Python) and compile to Mosaic on
-real hardware.
+On the ``cpu`` platform the kernels run in interpret mode (kernel bodies
+execute in Python), which is how the tests check them; on ``tpu`` they
+compile to Mosaic. Any other platform has no kernel path and is refused.
 """
 from __future__ import annotations
 
 import jax
 
-from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.jacobi3d import jacobi3d as _jacobi3d
 from repro.kernels.matmul import matmul as _matmul
@@ -16,7 +15,10 @@ from repro.kernels.ssd import ssd_chunk as _ssd_chunk
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"no Pallas kernel path for platform {backend!r}")
+    return backend == "cpu"
 
 
 def matmul(a, b, **kw):

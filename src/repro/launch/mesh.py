@@ -14,14 +14,22 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 from repro.models.sharding import resolve_spec
 
 
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the models place activations with
+    ``with_sharding_constraint`` (``models.sharding.constrain``), which
+    only names Auto axes."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh(data: int = 1, model: int = 1) -> Mesh:
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------

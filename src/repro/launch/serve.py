@@ -2,6 +2,10 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --smoke \
         --batch 4 --prompt-len 32 --gen 16
+
+Generates twice from the same prompts: the first call's time includes
+compiling, the second is the steady one, and the two must agree token for
+token (decoding is greedy).
 """
 from __future__ import annotations
 
@@ -35,24 +39,15 @@ class Engine:
     def generate(self, tokens: jax.Array, gen: int, extra=None):
         b, s = tokens.shape
         cache0 = self.model.init_cache(b, self.max_len)
-        batch = {"tokens": jnp.pad(tokens,
-                                   ((0, 0), (0, self.max_len - s)))}
-        if extra:
-            batch.update(extra)
-        # prefill over padded batch: simple engines prefill at fixed length;
-        # we prefill exactly s tokens then decode
-        batch["tokens"] = tokens
+        # prefill exactly the s prompt tokens, then decode
+        batch = {"tokens": tokens, **(extra or {})}
         nxt, cache = self._prefill(self.params, batch, cache0)
-        # grow prefill cache (length s) into decode capacity
-        def grow(a):
-            if hasattr(a, "ndim"):
-                for ax in range(1, min(a.ndim, 3)):
-                    if a.shape[ax] == s and a.shape[-1] != s:
-                        pad = [(0, 0)] * a.ndim
-                        pad[ax] = (0, self.max_len - s)
-                        return jnp.pad(a, pad)
-            return a
-        cache = jax.tree.map(grow, cache)
+        # the prefill cache holds s positions: pad each leaf at the end out
+        # to the decode capacity cache0 was allocated with
+        cache = jax.tree.map(
+            lambda a, full: jnp.pad(a, [(0, f - n) for n, f
+                                        in zip(a.shape, full.shape)]),
+            cache, cache0)
         out = [nxt]
         lengths = jnp.full((b,), s, jnp.int32)
         cur = nxt
@@ -80,7 +75,10 @@ def main(argv=None):
         else make_smoke_mesh(1, 1)
 
     with use_sharding(mesh):
-        params, _ = unbox(model.init(jax.random.PRNGKey(0)))
+        # one compiled program writes the parameters straight in their
+        # dtype; run eagerly, each bf16 matrix would first exist in f32
+        params = jax.jit(lambda key: unbox(model.init(key))[0])(
+            jax.random.PRNGKey(0))
         eng = Engine(model, params, args.batch,
                      args.prompt_len + args.gen)
         tokens = jax.random.randint(jax.random.PRNGKey(1),
@@ -93,14 +91,25 @@ def main(argv=None):
         if cfg.frontend == "vision":
             extra["vision_embeds"] = jnp.zeros(
                 (args.batch, cfg.frontend_tokens, cfg.d_model))
-        t0 = time.time()
-        out = eng.generate(tokens, args.gen, extra)
-        dt = time.time() - t0
-        print(f"generated {out.shape} in {dt:.2f}s "
-              f"({args.batch * args.gen / dt:.1f} tok/s)")
+        # the first call traces and compiles prefill and decode; the second
+        # runs the same shapes compiled
+        t0 = time.perf_counter()
+        first = jax.block_until_ready(eng.generate(tokens, args.gen, extra))
+        first_s = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(eng.generate(tokens, args.gen, extra))
+        steady_s = time.perf_counter() - t0
+        if not np.array_equal(np.asarray(first), np.asarray(out)):
+            raise RuntimeError("greedy decoding gave different tokens for "
+                               "the same prompts on a repeated call")
+        print(f"generated {out.shape}: first call (compiling) "
+              f"{first_s:.2f}s, steady call {steady_s:.2f}s "
+              f"({args.batch * args.gen / steady_s:.1f} tok/s)")
         print("sample:", np.asarray(out[0][:12]))
     return out
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

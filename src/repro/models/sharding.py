@@ -125,15 +125,9 @@ def resolve_spec(logical_axes: Sequence[Optional[str]],
 def _manual_axes() -> set:
     """Mesh axes currently in Manual (shard_map) mode — constraints must not
     mention them (e.g. the compressed-gradient pod-manual region)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        return {n for n, t in zip(am.axis_names, am.axis_types)
-                if "Manual" in str(t)}
-    except Exception:
-        # old jax: no abstract mesh — the compat shard_map shim records the
-        # manual axes in a thread-local while the body traces
-        import repro
-        return set(repro.compat_manual_axes())
+    am = jax.sharding.get_abstract_mesh()
+    return {n for n, t in zip(am.axis_names, am.axis_types)
+            if t == jax.sharding.AxisType.Manual}
 
 
 def constrain(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:
@@ -144,11 +138,6 @@ def constrain(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:
     spec = resolve_spec(logical_axes, shape=x.shape, mesh=mesh)
     manual = _manual_axes()
     if manual:
-        # old jax cannot apply constraints inside a partially-manual region
-        # at all (XLA trips an IsManualSubgroup check); constraints are
-        # advisory, so drop them there and let GSPMD pick layouts
-        if not hasattr(jax.sharding, "get_abstract_mesh"):
-            return x
         parts = []
         for p in spec:
             if p is None:

@@ -40,7 +40,7 @@ class Flags:
     loss_chunk: int = 1024           # seq chunk for the CE loss
     flash_block: int = 512
     use_pallas_flash: bool = False   # Pallas kernel for global attention
-                                     # (TPU; interpret=True off-TPU)
+                                     # (TPU; interpret mode on CPU)
 
 
 DEFAULT_FLAGS = Flags()

@@ -61,7 +61,6 @@ def compressed_pmean(x: jax.Array, axis_name: str,
     payload is the int8 blocks + f32 block scales (all-gather), then the
     mean is reconstructed locally — the compressible formulation of an
     all-reduce."""
-    import repro
     orig_shape = x.shape
     if x.ndim == 0:
         x = x[None]
@@ -72,62 +71,12 @@ def compressed_pmean(x: jax.Array, axis_name: str,
     q, scale, _ = quantize_int8(xin)
     local_deq = dequantize_int8(q, scale, x.shape, jnp.float32)
     new_residual = (xin - local_deq).reshape(orig_shape)
-    if not repro.COMPAT_SHARD_MAP:
-        # native shard_map: communicate the actual compressed payload —
-        # int8 blocks + f32 block scales — and reconstruct the mean locally
-        qg = jax.lax.all_gather(q, axis_name)    # [n, ..., blocks, BLOCK] i8
-        sg = jax.lax.all_gather(scale, axis_name)   # [n, ..., blocks]
-        total = jnp.sum(qg.astype(jnp.float32) * sg[..., None], axis=0)
-        deq_total = total.reshape(q.shape[:-2] + (-1,))[..., :x.shape[-1]]
-        mean = (deq_total.reshape(orig_shape) / n).astype(x.dtype)
-    else:
-        # old jax crashes on all_gather inside a partially-manual region
-        # (XLA spmd_partitioner IsManualSubgroup check); psum the locally
-        # dequantized payload instead — Σ_r q_r·s_r, bit-for-bit the same
-        # numerics (and the same error-feedback residual), just without the
-        # wire-format compression this in-process emulation cannot measure
-        # anyway
-        total = jax.lax.psum(local_deq, axis_name)
-        mean = (total.reshape(orig_shape) / n).astype(x.dtype)
+    qg = jax.lax.all_gather(q, axis_name)    # [n, ..., blocks, BLOCK] i8
+    sg = jax.lax.all_gather(scale, axis_name)   # [n, ..., blocks]
+    total = jnp.sum(qg.astype(jnp.float32) * sg[..., None], axis=0)
+    deq_total = total.reshape(q.shape[:-2] + (-1,))[..., :x.shape[-1]]
+    mean = (deq_total.reshape(orig_shape) / n).astype(x.dtype)
     return mean, new_residual
-
-
-def compressed_mean_stacked(x: jax.Array, residual: jax.Array
-                            ) -> Tuple[jax.Array, jax.Array]:
-    """``compressed_pmean`` over a *stacked* leading axis instead of a mesh
-    axis: ``x``/``residual`` are [n_pods, ...] and each pod's slice is
-    quantized independently (blocked along the last axis, exactly as the
-    distributed formulation does per rank). Returns (mean over pods, new
-    stacked residuals). Used by the compat path of the compressed-gradient
-    train step, where old jax cannot compile a pod-manual shard_map."""
-    scalar = x.ndim == 1                 # per-pod scalars: [n] → [n, 1]
-    if scalar:
-        x = x[:, None]
-        residual = residual[:, None]
-    n = x.shape[0]
-    xin = x.astype(jnp.float32) + residual
-    q, scale, _ = quantize_int8(xin)
-    local_deq = dequantize_int8(q, scale, xin.shape, jnp.float32)
-    new_residual = xin - local_deq
-    mean = (jnp.sum(local_deq, axis=0) / n).astype(x.dtype)
-    if scalar:
-        mean = mean[0]
-        new_residual = new_residual[:, 0]
-    return mean, new_residual
-
-
-def compressed_mean_stacked_tree(grads, residuals):
-    """Tree-wide ``compressed_mean_stacked``: grads/residuals are trees of
-    [n_pods, ...] leaves. Returns (mean grads [...], new residuals)."""
-    leaves, treedef = jax.tree_util.tree_flatten(grads)
-    res_leaves = jax.tree_util.tree_leaves(residuals)
-    outs, news = [], []
-    for g, r in zip(leaves, res_leaves):
-        m, nr = compressed_mean_stacked(g, r)
-        outs.append(m)
-        news.append(nr)
-    return (jax.tree_util.tree_unflatten(treedef, outs),
-            jax.tree_util.tree_unflatten(treedef, news))
 
 
 def compressed_pmean_tree(grads, axis_name: str, residuals=None):

@@ -52,37 +52,15 @@ def make_train_step(model: Model, tcfg: TrainConfig, param_axes=None
     od = tcfg.over_decompose
 
     def _compressed_grads(state, batch):
-        """Gradients with the cross-pod reduction compressed (int8 + EF).
-
-        Native jax: shard_map manual over 'pod' only; in-pod sharding stays
-        automatic and the compressed payload rides an all_gather. Old jax
-        (``repro.COMPAT_SHARD_MAP``) cannot compile a full model inside a
-        partially-manual region (XLA IsManualSubgroup checks), so the same
-        reduction runs as an in-graph scan over the pod dimension: per-pod
-        gradients are quantized independently and the dequantized payloads
-        are summed — numerics and error-feedback residuals identical to the
-        distributed formulation."""
-        import repro
+        """Gradients with the cross-pod reduction compressed (int8 + EF):
+        shard_map manual over 'pod' only; in-pod sharding stays automatic
+        and the compressed payload rides an all_gather."""
         from jax.sharding import PartitionSpec as PS
         from repro.models.sharding import active_mesh
-        from repro.train.compression import (compressed_mean_stacked_tree,
-                                             compressed_pmean_tree)
+        from repro.train.compression import compressed_pmean_tree
         assert state.ef is not None, \
             "compress_pod_grads needs EF residuals: init_train_state(..., " \
             "ef_pods=mesh.shape['pod'])"
-
-        if repro.COMPAT_SHARD_MAP:
-            npod = active_mesh().shape["pod"]
-
-            def split(x):
-                return x.reshape((npod, x.shape[0] // npod) + x.shape[1:])
-
-            per_pod = jax.tree.map(split, batch)
-            gs, ms = jax.lax.map(lambda mb: grad_fn(state.params, mb),
-                                 per_pod)
-            g, new_res = compressed_mean_stacked_tree(gs, state.ef)
-            m = jax.tree.map(lambda v: jnp.mean(v, axis=0), ms)
-            return g, m, new_res
 
         def body(params, batch_loc, residuals):
             from repro.models.sharding import constrain

@@ -28,9 +28,8 @@ def test_quantize_roundtrip_error_bounded(shape):
 
 def test_compressed_training_tracks_exact():
     """8 virtual devices, (pod=2, data=2, model=2): compressed-gradient
-    training must track exact training closely (error feedback). Runs on
-    every jax: native partial-manual shard_map when available, else the
-    scan-over-pods compat formulation (same numerics, see
+    training must track exact training closely (error feedback). The
+    gradient runs in a shard_map manual over 'pod' only (see
     train_step._compressed_grads)."""
     code = """
         import jax, jax.numpy as jnp
@@ -42,7 +41,8 @@ def test_compressed_training_tracks_exact():
         from repro.data import DataConfig, SyntheticLM
         cfg = get_smoke_config('yi_9b')
         m = build_smoke(cfg)
-        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
         opt = AdamWConfig(lr_peak=1e-3, warmup_steps=2, total_steps=50)
         data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=32,
                                       global_batch=8, seed=5))

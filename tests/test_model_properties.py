@@ -216,7 +216,8 @@ def test_moe_tiny_capacity_drops_gracefully():
                      M.moe_init(KEY, 8, mcfg, True, dtype=jnp.float32),
                      is_leaf=lambda x: isinstance(x, L.Boxed))
     xf = jax.random.normal(KEY, (16, 8))
-    mesh = jax.make_mesh((1,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("model",))
 
     def run(cf):
         body = lambda xloc: M._ep_local(p, xloc, mcfg, True, "model", cf)[0]

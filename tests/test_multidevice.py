@@ -77,7 +77,8 @@ def test_moe_ep_matches_dense_oracle():
         from repro.models import moe as M
         from repro.models.layers import unbox
         from repro.models.sharding import use_sharding
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ('data', 'model'))
         mcfg = MoEConfig(num_experts=8, top_k=2, d_ff_expert=32)
         key = jax.random.PRNGKey(0)
         p, _ = unbox(M.moe_init(key, 16, mcfg, True, dtype=jnp.float32))
@@ -98,7 +99,8 @@ def test_spmd_jacobi_multidevice():
     run_py("""
         import numpy as np, jax
         from repro.apps.jacobi3d import run_reference, run_spmd
-        mesh = jax.make_mesh((4, 1), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 1), ('data', 'model'))
         rng = np.random.default_rng(0)
         u0 = rng.random((16, 8, 8)).astype(np.float32)
         want = run_reference(u0, 3)
@@ -115,7 +117,8 @@ def test_seq_sharded_decode_matches_plain():
         from repro.models.attention import (decode_attention,
                                             seq_sharded_decode)
         from repro.models.sharding import use_sharding
-        mesh = jax.make_mesh((4, 1), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 1), ('data', 'model'))
         key = jax.random.PRNGKey(0)
         b, t, kh, g, d = 1, 64, 2, 2, 16
         ks = jax.random.split(key, 3)
@@ -141,7 +144,8 @@ def test_elastic_restore_to_smaller_mesh(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as PS
         from repro.checkpoint import Checkpointer
-        mesh = jax.make_mesh((8,), ('data',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ('data',))
         x = jax.device_put(jnp.arange(64.0).reshape(8, 8),
                            NamedSharding(mesh, PS('data')))
         ck = Checkpointer({ckdir!r}, async_save=False)
@@ -152,7 +156,8 @@ def test_elastic_restore_to_smaller_mesh(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as PS
         from repro.checkpoint import Checkpointer
-        mesh = jax.make_mesh((4,), ('data',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ('data',))
         ck = Checkpointer({ckdir!r})
         abs_state = {{'x': jax.ShapeDtypeStruct((8, 8), jnp.float32)}}
         shardings = {{'x': NamedSharding(mesh, PS('data'))}}
@@ -171,6 +176,7 @@ def test_sequence_parallel_rules_preserve_numerics():
         from repro.configs import get_smoke_config
         from repro.models import build_smoke
         from repro.models.layers import unbox
+        from repro.launch.mesh import make_mesh
         from repro.models.sharding import use_sharding
         for arch in ('gemma3_27b', 'mamba2_370m'):
             cfg = get_smoke_config(arch)
@@ -185,7 +191,7 @@ def test_sequence_parallel_rules_preserve_numerics():
                 x, _, aux = m.apply(p, b, mode='train')
                 return m.loss(p, x, b['labels']) + aux
             want = float(jax.jit(loss)(params, batch))
-            mesh = jax.make_mesh((2, 4), ('data', 'model'))
+            mesh = make_mesh((2, 4), ('data', 'model'))
             with use_sharding(mesh, {'act_seq': 'model'}):
                 got = float(jax.jit(loss)(params, batch))
             assert abs(got - want) < 1e-3, (arch, got, want)
@@ -201,7 +207,8 @@ def test_seq_sharded_decode_model_axis():
         from repro.models.attention import (decode_attention,
                                             seq_sharded_decode)
         from repro.models.sharding import use_sharding
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ('data', 'model'))
         key = jax.random.PRNGKey(0)
         b, t, kh, g, d = 4, 32, 2, 2, 16
         ks = jax.random.split(key, 3)

@@ -156,7 +156,7 @@ def _apply(decorators):
 
 
 @_apply(_dag_decorators)
-def test_random_dag_equals_sequential(ops_list=None):
+def test_random_dag_equals_sequential(ops_list):
     """Property: any random read/write program gives results identical to
     sequential execution (the paper's correctness guarantee)."""
     with Runtime(RuntimeConfig(memory_capacity=1 << 28)) as rt:
