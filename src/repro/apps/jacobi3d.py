@@ -40,6 +40,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from repro.core import HeteroTask, Runtime
@@ -93,20 +94,21 @@ def run_tasked(u0: np.ndarray, iters: int, runtime: Runtime,
     overlap automatically (the paper's Fig. 14 pipeline)."""
     n_workers = len(runtime.devices)
     plan = plan_decomposition(u0.shape, n_workers, over_decomposition)
-    chunks = {c.cid: runtime.hetero_object(
-        np.ascontiguousarray(u0[c.lo[0]:c.hi[0], c.lo[1]:c.hi[1],
-                                c.lo[2]:c.hi[2]]), name=f"chunk{c.cid}")
-        for c in plan.chunks}
-    # halo buffers per (chunk, face)
-    faces = {}
-    for c in plan.chunks:
-        s = c.shape
-        face_shapes = {"lo0": (s[1], s[2]), "hi0": (s[1], s[2]),
-                       "lo1": (s[0], s[2]), "hi1": (s[0], s[2]),
-                       "lo2": (s[0], s[1]), "hi2": (s[0], s[1])}
-        for tag, fs in face_shapes.items():
-            faces[(c.cid, tag)] = runtime.hetero_object(
-                np.zeros(fs, u0.dtype), name=f"halo{c.cid}:{tag}")
+    with TraceAnnotation("jacobi.split"):
+        chunks = {c.cid: runtime.hetero_object(
+            np.ascontiguousarray(u0[c.lo[0]:c.hi[0], c.lo[1]:c.hi[1],
+                                    c.lo[2]:c.hi[2]]), name=f"chunk{c.cid}")
+            for c in plan.chunks}
+        # halo buffers per (chunk, face)
+        faces = {}
+        for c in plan.chunks:
+            s = c.shape
+            face_shapes = {"lo0": (s[1], s[2]), "hi0": (s[1], s[2]),
+                           "lo1": (s[0], s[2]), "hi1": (s[0], s[2]),
+                           "lo2": (s[0], s[1]), "hi2": (s[0], s[1])}
+            for tag, fs in face_shapes.items():
+                faces[(c.cid, tag)] = runtime.hetero_object(
+                    np.zeros(fs, u0.dtype), name=f"halo{c.cid}:{tag}")
 
     # kernels created once → the runtime's jit cache hits across iterations
     def make_face_kernel(tag: str):
@@ -155,8 +157,10 @@ def run_tasked(u0: np.ndarray, iters: int, runtime: Runtime,
 
     out = np.empty_like(u0)
     for c in plan.chunks:
-        out[c.lo[0]:c.hi[0], c.lo[1]:c.hi[1], c.lo[2]:c.hi[2]] = \
-            chunks[c.cid].get()
+        part = chunks[c.cid].get()
+        # the download stays outside: the span holds the host copy alone
+        with TraceAnnotation("jacobi.assemble"):
+            out[c.lo[0]:c.hi[0], c.lo[1]:c.hi[1], c.lo[2]:c.hi[2]] = part
     return out
 
 

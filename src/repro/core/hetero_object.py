@@ -17,6 +17,7 @@ import threading
 from typing import Any, Dict, Optional, Set, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import sanitizer
 from repro.core.futures import HFuture
@@ -100,9 +101,10 @@ class HeteroObject:
 
     def get(self, timeout: Optional[float] = None) -> np.ndarray:
         """Convenience: request, wait, copy out, release."""
-        fut = self.request_host(write=False)
-        arr = np.array(fut.get(timeout))
-        self.release()
+        with TraceAnnotation("rt.get"):
+            fut = self.request_host(write=False)
+            arr = np.array(fut.get(timeout))
+            self.release()
         return arr
 
     def free(self) -> None:

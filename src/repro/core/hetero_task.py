@@ -78,6 +78,7 @@ class HeteroTask:
         self.unresolved: int = 0
         self.dependents: List["HeteroTask"] = []
         self.chosen_device: Optional[int] = None
+        self.ready_at: float = 0.0     # perf_counter when last made READY
 
     # builder API -----------------------------------------------------------
     class _ArgMode:

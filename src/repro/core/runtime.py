@@ -77,6 +77,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import clock
 from repro.core import dependency as dep
@@ -267,7 +268,8 @@ class Runtime:
                        "graphs_traced": 0, "graph_replays": 0,
                        "graph_invalidations": 0, "replayed_tasks": 0,
                        "lineage_recomputes": 0, "recompute_depth_peak": 0,
-                       "task_retries": 0, "tasks_failed": 0}
+                       "task_retries": 0, "tasks_failed": 0,
+                       "ready_wait_s": 0.0}
         # lineage ledger: producer records for lost-replica recovery
         self.lineage: Optional[LineageLedger] = (
             LineageLedger() if self.cfg.lineage_depth > 0 else None)
@@ -358,25 +360,27 @@ class Runtime:
 
     def submit(self, task: HeteroTask, kernel: Callable) -> HFuture:
         """Enqueue an execution request; returns the task's future."""
-        task.kernel = kernel
-        tracer = self._tracer
-        if tracer is not None:
+        with TraceAnnotation("rt.submit", task=task.id):
+            task.kernel = kernel
+            tracer = self._tracer
+            if tracer is not None:
+                with self._lock:
+                    task.state = TaskState.SUBMITTED
+                    self._tasks_pending += 1
+                    self._stats["tasks"] += 1
+                # the tracer either parks the task for a compiled replay
+                # (skipping pins / dependency inference / scheduling) or
+                # tells us to run it interpreted while it records the
+                # window
+                if not tracer.on_submit(task, kernel):
+                    self._enqueue(task)
+                return task.future
             with self._lock:
                 task.state = TaskState.SUBMITTED
                 self._tasks_pending += 1
                 self._stats["tasks"] += 1
-            # the tracer either parks the task for a compiled replay
-            # (skipping pins / dependency inference / scheduling) or
-            # tells us to run it interpreted while it records the window
-            if not tracer.on_submit(task, kernel):
-                self._enqueue(task)
+                self._pin_and_schedule_locked(task)
             return task.future
-        with self._lock:
-            task.state = TaskState.SUBMITTED
-            self._tasks_pending += 1
-            self._stats["tasks"] += 1
-            self._pin_and_schedule_locked(task)
-        return task.future
 
     def _pin_and_schedule_locked(self, task: HeteroTask) -> None:
         # ledger-owned pins: every argument is protected from
@@ -388,9 +392,21 @@ class Runtime:
         if n > 0:
             task.state = TaskState.BLOCKED
         else:
-            task.state = TaskState.READY
-            self.scheduler.push(task)
+            self._push_ready_locked(task)
         self._work.notify_all()
+
+    def _push_ready_locked(self, task: HeteroTask) -> None:
+        task.state = TaskState.READY
+        task.ready_at = time.perf_counter()
+        self.scheduler.push(task)
+
+    def _claim_locked(self, task: HeteroTask, dev: int) -> None:
+        """A worker took ``task`` off the scheduler for ``dev``: the time
+        it sat READY is the scheduler's queue time."""
+        self._stats["ready_wait_s"] += time.perf_counter() - task.ready_at
+        task.state = TaskState.RUNNING
+        task.chosen_device = dev
+        self.scheduler.load[dev] += 1
 
     def _enqueue(self, task: HeteroTask) -> None:
         """Interpreted-path scheduling for an already-accounted task
@@ -629,7 +645,9 @@ class Runtime:
         else:
             dev_arr = obj.copies[src]
             t0 = time.perf_counter()
-            arr, pooled = self._download_device(self._device(src), dev_arr)
+            with TraceAnnotation("rt.d2h"):
+                arr, pooled = self._download_device(self._device(src),
+                                                    dev_arr)
             self.topology.observe(src, HOST, obj.nbytes,
                                   time.perf_counter() - t0)
             self._stats["transfers_d2h"] += 1
@@ -676,7 +694,8 @@ class Runtime:
         path blocks, so its sample is honest; the simple path measures
         dispatch+copy, which the EWMA smooths)."""
         t0 = time.perf_counter()
-        arr = self._upload_host_inner(device, host_arr)
+        with TraceAnnotation("rt.h2d"):
+            arr = self._upload_host_inner(device, host_arr)
         self.topology.observe(HOST, device.info.device_id,
                               host_arr.nbytes, time.perf_counter() - t0)
         return arr
@@ -852,9 +871,10 @@ class Runtime:
                     pass
             self.residency.ensure_capacity(device_id, obj.nbytes,
                                            self._evict)
-            dev_arr = device_api.transfer(self._device(src_dev),
-                                          self._device(device_id), src_arr,
-                                          observer=self.topology.observe)
+            with TraceAnnotation("rt.d2d"):
+                dev_arr = device_api.transfer(
+                    self._device(src_dev), self._device(device_id), src_arr,
+                    observer=self.topology.observe)
             self._stats["transfers_d2d"] += 1
             self._stats["bytes_d2d"] += obj.nbytes
         else:
@@ -933,9 +953,7 @@ class Runtime:
             if item is None:
                 return None
             task, dev = item
-            task.state = TaskState.RUNNING
-            task.chosen_device = dev
-            self.scheduler.load[dev] += 1
+            self._claim_locked(task, dev)
         objs = []
         seen = set()
         for ref in task.args:
@@ -976,7 +994,8 @@ class Runtime:
                 if self._shutdown:
                     return
                 if async_mode and gate["n"] >= self.cfg.inflight:
-                    self._work.wait(timeout=self.cfg.poll_interval_s * 20)
+                    with TraceAnnotation("rt.wait.inflight"):
+                        self._work.wait(timeout=self.cfg.poll_interval_s * 20)
                     continue
             if staged:
                 task, dev, pmap = staged.popleft()
@@ -987,21 +1006,20 @@ class Runtime:
                         return
                     item = self.scheduler.pop(device_hint)
                     if item is not None:
-                        task, dev = item
-                        task.state = TaskState.RUNNING
-                        task.chosen_device = dev
-                        self.scheduler.load[dev] += 1
+                        self._claim_locked(*item)
             if item is None:
                 # nothing runnable: park until a push or a completion
                 # event (retire → _finish) notifies the condition
                 with self._lock:
                     if self._shutdown:
                         return
-                    self._work.wait(timeout=self.cfg.poll_interval_s * 20)
+                    with TraceAnnotation("rt.wait.idle"):
+                        self._work.wait(timeout=self.cfg.poll_interval_s * 20)
                 continue
             task, dev = item
             try:
-                handle = self._launch(task, dev, pmap)
+                with TraceAnnotation("rt.launch", task=task.id):
+                    handle = self._launch(task, dev, pmap)
             except BaseException as e:
                 # bounded relaunch (cfg.task_retries) before the error
                 # surfaces: injected kernel faults / transient device
@@ -1013,9 +1031,8 @@ class Runtime:
                     with self._lock:
                         self._stats["task_retries"] += 1
                         self.scheduler.load[dev] -= 1
-                        task.state = TaskState.READY
                         task.chosen_device = None
-                        self.scheduler.push(task)
+                        self._push_ready_locked(task)
                         self._work.notify_all()
                     continue
                 self._finish(task, error=e)
@@ -1090,8 +1107,9 @@ class Runtime:
                     self._inject_task_faults -= 1
                     raise InjectedTaskFault(
                         f"injected kernel fault (task {task.name!r})")
-        handle = self._device(device_id).launch(
-            task.kernel, tuple(dev_args), donate=tuple(donate))
+        with TraceAnnotation("rt.dispatch"):
+            handle = self._device(device_id).launch(
+                task.kernel, tuple(dev_args), donate=tuple(donate))
         # bind outputs back onto the written hetero_objects
         outs = handle if isinstance(handle, (tuple, list)) else (handle,)
         wi = 0
@@ -1123,24 +1141,24 @@ class Runtime:
         return handle
 
     def _finish(self, task: HeteroTask, result=None, error=None):
-        for obj in {id(r.obj): r.obj for r in task.args}.values():
-            self.residency.unpin(obj)
-        with self._lock:
-            if error is not None:
-                task.state = TaskState.FAILED
-                self._stats["tasks_failed"] += 1
-                if self.cfg.strict_errors and len(self._failed_tasks) < 64:
-                    self._failed_tasks.append(error)
-            else:
-                task.state = TaskState.DONE
-            if task.chosen_device is not None:
-                self.scheduler.load[task.chosen_device] -= 1
-            ready = dep.retire(task)
-            for r in ready:
-                r.state = TaskState.READY
-                self.scheduler.push(r)
-            self._tasks_pending -= 1
-            self._work.notify_all()
+        with TraceAnnotation("rt.retire", task=task.id):
+            for obj in {id(r.obj): r.obj for r in task.args}.values():
+                self.residency.unpin(obj)
+            with self._lock:
+                if error is not None:
+                    task.state = TaskState.FAILED
+                    self._stats["tasks_failed"] += 1
+                    if (self.cfg.strict_errors
+                            and len(self._failed_tasks) < 64):
+                        self._failed_tasks.append(error)
+                else:
+                    task.state = TaskState.DONE
+                if task.chosen_device is not None:
+                    self.scheduler.load[task.chosen_device] -= 1
+                for r in dep.retire(task):
+                    self._push_ready_locked(r)
+                self._tasks_pending -= 1
+                self._work.notify_all()
         if error is not None:
             task.future.set_error(error)
         else:

@@ -55,6 +55,8 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.core import sanitizer
 from repro.core import device_api
 from repro.core.hetero_object import HOST
@@ -196,7 +198,8 @@ class GraphTracer:
             g = self._graph
             if g is not None and not self._deviated and self._parked:
                 if self._match_idx == len(g.nodes):
-                    self._replay_locked()
+                    with TraceAnnotation("rt.replay"):
+                        self._replay_locked()
                     return
                 # fewer submits than the trace expects: structure changed
                 self._invalidate_locked()
