@@ -157,10 +157,9 @@ def run_tasked(u0: np.ndarray, iters: int, runtime: Runtime,
 
     out = np.empty_like(u0)
     for c in plan.chunks:
-        part = chunks[c.cid].get()
-        # the download stays outside: the span holds the host copy alone
-        with TraceAnnotation("jacobi.assemble"):
-            out[c.lo[0]:c.hi[0], c.lo[1]:c.hi[1], c.lo[2]:c.hi[2]] = part
+        # each chunk comes down straight into its block of the output
+        chunks[c.cid].get(out=out[c.lo[0]:c.hi[0], c.lo[1]:c.hi[1],
+                                  c.lo[2]:c.hi[2]])
     return out
 
 

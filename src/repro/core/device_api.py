@@ -81,6 +81,12 @@ class Device(abc.ABC):
         np.copyto(out, self.download(dev_array))
         return out
 
+    def start_download(self, dev_array: Any) -> Any:
+        """Begin copying a resident array to the host, so that a later
+        ``download``/``download_into`` of it overlaps the host's own work;
+        returns the array. The default starts nothing."""
+        return dev_array
+
     @abc.abstractmethod
     def transfer_from(self, src: Optional["Device"], dev_array: Any) -> Any:
         """Copy ``dev_array`` (resident on ``src``, which may be None when
@@ -180,6 +186,10 @@ class JaxDevice(Device):
 
     def download(self, dev_array: Any) -> np.ndarray:
         return np.asarray(dev_array)
+
+    def start_download(self, dev_array: Any) -> Any:
+        dev_array.copy_to_host_async()
+        return dev_array
 
     def transfer_from(self, src: "Device", dev_array: Any) -> Any:
         # jax.device_put on a committed jax.Array issues the copy directly

@@ -99,9 +99,19 @@ class HeteroObject:
     def release(self) -> None:
         self._rt._release_host(self)
 
-    def get(self, timeout: Optional[float] = None) -> np.ndarray:
-        """Convenience: request, wait, copy out, release."""
+    def get(self, timeout: Optional[float] = None,
+            out: Optional[np.ndarray] = None) -> np.ndarray:
+        """Convenience: request, wait, copy out, release; returns a private
+        array.
+
+        With ``out`` (a host array of this object's shape and dtype,
+        contiguous or a strided view) the freshest copy is downloaded
+        straight into ``out``, which is returned: no staging buffer, no
+        copy-out, and no host copy kept by the runtime. A shape or dtype
+        mismatch raises ``ValueError``."""
         with TraceAnnotation("rt.get"):
+            if out is not None:
+                return self._rt._get_into(self, out, timeout)
             fut = self.request_host(write=False)
             arr = np.array(fut.get(timeout))
             self.release()

@@ -51,9 +51,11 @@ Large host→device copies are chunked through the ``StagingPool``
 (page-locked buffer analogue) in ``staging_chunk_bytes`` pieces, and the
 mirrored device→host path stages downloads into pooled buffers the same
 way — so host copies never alias device buffers that donation might
-recycle. Pool buffers are recycled: staging buffers return to the pool
-when a host copy is dropped, transfer futures return to the
-``RequestPool`` once consumed.
+recycle. ``HeteroObject.get(out=...)`` downloads the same pieces straight
+into the caller's array instead, and keeps no host copy
+(``stats()["d2h_direct"]`` counts those). Pool buffers are recycled:
+staging buffers return to the pool when a host copy is dropped, transfer
+futures return to the ``RequestPool`` once consumed.
 
 Configuration toggles map 1:1 to the paper's optimization ladder (Fig. 8)
 so the benchmark can reproduce it:
@@ -116,7 +118,8 @@ class RuntimeConfig:
     prefetch: bool = True         # argument prefetch pipeline (§4.1.3)
     prefetch_depth: int = 1       # tasks claimed ahead per worker
     memory_capacity: Optional[int] = None
-    staging_chunk_bytes: int = 8 << 20   # chunk host uploads above this size
+    # host uploads and downloads above this size move in pieces of it
+    staging_chunk_bytes: int = 8 << 20
     poll_interval_s: float = 0.0005
     # -- interconnect topology / message protocol (paper §3.2.3 + §4.2) --
     topology_probe: bool = True   # startup micro-probe seeds the model
@@ -269,7 +272,7 @@ class Runtime:
                        "graph_invalidations": 0, "replayed_tasks": 0,
                        "lineage_recomputes": 0, "recompute_depth_peak": 0,
                        "task_retries": 0, "tasks_failed": 0,
-                       "ready_wait_s": 0.0}
+                       "ready_wait_s": 0.0, "d2h_direct": 0}
         # lineage ledger: producer records for lost-replica recovery
         self.lineage: Optional[LineageLedger] = (
             LineageLedger() if self.cfg.lineage_depth > 0 else None)
@@ -625,37 +628,92 @@ class Runtime:
                     obj._orphan_host = arr
                 obj._pooled_host = False
 
-    def _stage_to_host(self, obj: HeteroObject) -> np.ndarray:
+    def _host_or_source(self, obj: HeteroObject
+                        ) -> Tuple[Optional[np.ndarray], Optional[int]]:
+        """(host copy, None) when the object has one, else (None, a device
+        holding a valid copy); (None, None) when no replica is left, even
+        after replaying the recorded producer chain (bounded, cycle-safe)."""
         with obj.lock:
             if HOST in obj.copies:
-                return obj.copies[HOST]
+                return obj.copies[HOST], None
             src = next(iter(obj.copies), None)
         if src is None and self.lineage is not None:
-            # no valid replica anywhere: before conjuring zeros, try to
-            # replay the recorded producer chain (bounded, cycle-safe)
             if self._lineage_recover(obj):
                 with obj.lock:
                     if HOST in obj.copies:
-                        return obj.copies[HOST]
+                        return obj.copies[HOST], None
                     src = next(iter(obj.copies), None)
+        return None, src
+
+    def _counted_download(self, obj: HeteroObject, src: int,
+                          download: Callable[[Device, Any], Any]) -> Any:
+        """One D2H transfer of ``obj``'s copy on device ``src``:
+        ``download(device, device array)`` inside the ``rt.d2h`` span,
+        timed into the interconnect model and counted in the stats."""
+        dev_arr = obj.copies[src]
+        t0 = time.perf_counter()
+        with TraceAnnotation("rt.d2h"):
+            result = download(self._device(src), dev_arr)
+        self.topology.observe(src, HOST, obj.nbytes,
+                              time.perf_counter() - t0)
+        self._stats["transfers_d2h"] += 1
+        self._stats["bytes_d2h"] += obj.nbytes
+        return result
+
+    def _stage_to_host(self, obj: HeteroObject) -> np.ndarray:
+        host, src = self._host_or_source(obj)
+        if host is not None:
+            return host
         if src is None:
             arr = self.staging.acquire(obj.shape, obj.dtype)
             arr[...] = 0
             pooled = True
         else:
-            dev_arr = obj.copies[src]
-            t0 = time.perf_counter()
-            with TraceAnnotation("rt.d2h"):
-                arr, pooled = self._download_device(self._device(src),
-                                                    dev_arr)
-            self.topology.observe(src, HOST, obj.nbytes,
-                                  time.perf_counter() - t0)
-            self._stats["transfers_d2h"] += 1
-            self._stats["bytes_d2h"] += obj.nbytes
+            arr, pooled = self._counted_download(obj, src,
+                                                 self._download_device)
         with obj.lock:
             obj.copies[HOST] = arr
             obj._pooled_host = pooled
         return arr
+
+    def _get_into(self, obj: HeteroObject, out: np.ndarray,
+                  timeout: Optional[float]) -> np.ndarray:
+        """``HeteroObject.get(out=...)``: the host access protocol of
+        ``_request_host`` run on the caller's thread, with the freshest
+        copy landing in the caller's ``out`` and nowhere else — no staging
+        buffer, no copy-out, and no HOST copy installed, since ``out``
+        belongs to the caller."""
+        if out.shape != tuple(obj.shape) or out.dtype != obj.dtype:
+            raise ValueError(
+                f"get(out=): {obj.name} is {obj.dtype}{list(obj.shape)}, "
+                f"out is {out.dtype}{list(out.shape)}")
+        if self._tracer is not None:
+            self._tracer.flush()     # parked writes must be observable
+        self.residency.pin(obj)      # until _release_host
+        with obj.lock:
+            obj.host_pins += 1
+        try:
+            with self._lock:
+                lw = obj.last_writer
+            if lw is not None and not lw.done():
+                written = threading.Event()
+                lw.future.add_done_callback(lambda _: written.set())
+                if not written.wait(timeout):
+                    raise TimeoutError(f"get: {obj.name}'s writer still "
+                                       f"running")
+            host, src = self._host_or_source(obj)
+            if host is not None:
+                np.copyto(out, host)
+            elif src is None:
+                out[...] = 0
+            else:
+                self._counted_download(
+                    obj, src, lambda dev, arr: self._download_pieces(
+                        dev, arr, out))
+                self._stats["d2h_direct"] += 1
+        finally:
+            self._release_host(obj)
+        return out
 
     def _download_device(self, device: Device,
                          dev_arr: Any) -> Tuple[np.ndarray, bool]:
@@ -668,23 +726,31 @@ class Runtime:
         if not self.staging.enabled:
             # no pool: still a private copy, never an aliasing view
             return np.array(device.download(dev_arr)), False
-        shape = tuple(dev_arr.shape)
-        dtype = np.dtype(dev_arr.dtype)
-        buf = self.staging.acquire(shape, dtype)
-        chunk = self.cfg.staging_chunk_bytes
-        nbytes = buf.nbytes
-        if (chunk <= 0 or nbytes <= chunk or buf.ndim == 0
-                or shape[0] < 2):
-            device.download_into(dev_arr, buf)
-            return buf, True
-        # chunked: slice on device, download piecewise into the pool
-        # buffer so no full-size intermediate host array materializes
-        row_bytes = max(1, nbytes // shape[0])
-        rows_per = max(1, chunk // row_bytes)
-        for i in range(0, shape[0], rows_per):
-            device.download_into(dev_arr[i:i + rows_per],
-                                 buf[i:i + rows_per])
+        buf = self.staging.acquire(tuple(dev_arr.shape),
+                                   np.dtype(dev_arr.dtype))
+        self._download_pieces(device, dev_arr, buf)
         return buf, True
+
+    def _download_pieces(self, device: Device, dev_arr: Any,
+                         out: np.ndarray) -> None:
+        """Copy ``dev_arr`` into the host array ``out`` (its shape, any
+        strides). Above ``staging_chunk_bytes`` the array is sliced on the
+        device and comes down in row pieces, each straight into its slice
+        of ``out``, so no full-size intermediate host array materializes.
+        The next piece's copy starts before the current one is written
+        into ``out``, so the link overlaps the host's writes."""
+        chunk = self.cfg.staging_chunk_bytes
+        nbytes, rows = out.nbytes, out.shape[0] if out.ndim else 0
+        if chunk <= 0 or nbytes <= chunk or rows < 2:
+            device.download_into(dev_arr, out)
+            return
+        rows_per = max(1, chunk // max(1, nbytes // rows))
+        ahead = device.start_download(dev_arr[:rows_per])
+        for i in range(0, rows, rows_per):
+            piece, j = ahead, i + rows_per
+            if j < rows:
+                ahead = device.start_download(dev_arr[j:j + rows_per])
+            device.download_into(piece, out[i:j])
 
     def _upload_host(self, device: Device, host_arr: np.ndarray) -> Any:
         """Host→device copy; large arrays stream through pooled staging

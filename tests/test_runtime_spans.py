@@ -58,7 +58,7 @@ def test_one_span_per_task(traced_solve, name):
     assert len(traced_solve["spans"][name]) == traced_solve["stats"]["tasks"]
 
 
-@pytest.mark.parametrize("name", ["rt.d2h", "rt.get", "jacobi.assemble"])
+@pytest.mark.parametrize("name", ["rt.d2h", "rt.get"])
 def test_one_span_per_chunk(traced_solve, name):
     assert len(traced_solve["spans"][name]) == traced_solve["n_chunks"]
 
@@ -86,10 +86,12 @@ def test_one_split_per_solve(traced_solve):
     assert len(traced_solve["spans"]["jacobi.split"]) == 1
 
 
-def test_no_download_inside_the_assembly(traced_solve):
-    for lo, hi, _ in traced_solve["spans"]["jacobi.assemble"]:
-        for s, e, _ in traced_solve["spans"]["rt.d2h"]:
-            assert e <= lo or s >= hi
+def test_no_separate_assembly_copy(traced_solve):
+    assert "jacobi.assemble" not in traced_solve["spans"]
+
+
+def test_every_chunk_downloads_into_the_output(traced_solve):
+    assert traced_solve["stats"]["d2h_direct"] == traced_solve["n_chunks"]
 
 
 @pytest.mark.parametrize("child, parent", [("rt.dispatch", "rt.launch"),

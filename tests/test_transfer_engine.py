@@ -5,6 +5,9 @@ ready queues, and staging/request pool recycling.
 conftest.py forces a 2-device CPU view, so every test here exercises real
 cross-device movement in-process.
 """
+import threading
+
+import jax
 import numpy as np
 import pytest
 
@@ -179,6 +182,159 @@ def test_chunked_host_upload_through_staging_pool():
         rt.barrier()
         np.testing.assert_allclose(x.get(), data, rtol=1e-6)
         assert rt.staging.hits + rt.staging.misses > 1   # chunked acquires
+
+
+# ---------------------------------------------------------------------------
+# HeteroObject.get(out=...): download into the caller's buffer
+# ---------------------------------------------------------------------------
+
+# whole numbers: every result below is exact in float32
+DATA = np.arange(64 * 48, dtype=np.float32).reshape(64, 48)
+
+
+def _object(rt, where):
+    """An object whose only valid copy is on a device, on the host, or
+    nowhere (never written)."""
+    if where == "unwritten":
+        return rt.hetero_object(shape=DATA.shape, dtype=DATA.dtype)
+    x = rt.hetero_object(DATA.copy())
+    if where == "device":
+        rt.run(lambda v: v * 2.0 + 1.0, [(x, "rw")])
+        rt.barrier()
+        assert HOST not in x.valid_spaces()
+    return x
+
+
+def _out(layout):
+    """A host array of DATA's shape: fresh, or a strided view of a larger
+    array whose other elements are NaN."""
+    if layout == "contiguous":
+        return np.empty_like(DATA), None
+    big = np.full((2 * DATA.shape[0], DATA.shape[1] + 5), np.nan,
+                  np.float32)
+    return big[::2, 3:3 + DATA.shape[1]], big
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "strided"])
+@pytest.mark.parametrize("where", ["device", "host", "unwritten"])
+def test_get_into_equals_get(where, layout):
+    with Runtime(RuntimeConfig(memory_capacity=1 << 28)) as rt:
+        x = _object(rt, where)
+        out, big = _out(layout)
+        got = x.get(out=out)
+        assert got is out
+        np.testing.assert_array_equal(out, x.get())
+        if big is not None:
+            # only the view was written
+            assert np.isnan(big).sum() == big.size - DATA.size
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "strided"])
+def test_get_into_downloads_in_pieces(layout):
+    # 64 rows of 192 B: 1 KiB pieces are 5 rows, 13 pieces
+    cfg = RuntimeConfig(memory_capacity=1 << 28, staging_chunk_bytes=1 << 10)
+    with Runtime(cfg) as rt:
+        x = _object(rt, "device")
+        (dev,) = {rt._device(s) for s in x.valid_spaces()}
+        calls, started = [], []
+        plain, start = dev.download_into, dev.start_download
+        dev.download_into = lambda a, o: calls.append(o.shape) or plain(a, o)
+        dev.start_download = lambda a: started.append(a.shape) or start(a)
+        out, _ = _out(layout)
+        x.get(out=out)
+        dev.download_into, dev.start_download = plain, start
+        assert len(calls) == 13
+        assert sum(shape[0] for shape in calls) == DATA.shape[0]
+        assert started == calls     # every piece was started ahead
+        np.testing.assert_array_equal(out, DATA * 2.0 + 1.0)
+
+
+@pytest.mark.parametrize("shape, dtype", [((64, 47), np.float32),
+                                          ((64, 48), np.float64)])
+def test_get_into_refuses_another_shape_or_dtype(shape, dtype):
+    with Runtime(RuntimeConfig(memory_capacity=1 << 28)) as rt:
+        x = _object(rt, "device")
+        with pytest.raises(ValueError, match="get\\(out=\\)"):
+            x.get(out=np.empty(shape, dtype))
+        assert not rt.residency.pinned(x)
+
+
+def test_get_into_keeps_no_host_copy_and_no_staging_buffer():
+    with Runtime(RuntimeConfig(memory_capacity=1 << 28)) as rt:
+        x = _object(rt, "device")
+        before = rt.stats()
+        x.get(out=np.empty_like(DATA))
+        after = rt.stats()
+        assert HOST not in x.valid_spaces()
+        assert (after["staging_hits"], after["staging_misses"]) == \
+            (before["staging_hits"], before["staging_misses"])
+
+
+@pytest.mark.parametrize("where, downloads", [("device", 1), ("host", 0),
+                                              ("unwritten", 0)])
+def test_get_into_counts_its_download(where, downloads):
+    with Runtime(RuntimeConfig(memory_capacity=1 << 28)) as rt:
+        x = _object(rt, where)
+        before = rt.stats()
+        x.get(out=np.empty_like(DATA))
+        after = rt.stats()
+        rise = {k: after[k] - before[k]
+                for k in ("transfers_d2h", "bytes_d2h", "d2h_direct")}
+        assert rise == {"transfers_d2h": downloads,
+                        "bytes_d2h": downloads * DATA.nbytes,
+                        "d2h_direct": downloads}
+
+
+def _held_writer(rt, x, gate):
+    """Submit a writer task of ``x`` that runs until ``gate`` is set."""
+    def held(a):
+        gate.wait(10)
+        return a + 1.0
+
+    def kernel(v):
+        return jax.pure_callback(held, jax.ShapeDtypeStruct(v.shape,
+                                                            v.dtype), v)
+    return rt.run(kernel, [(x, "rw")])
+
+
+def test_get_into_waits_for_the_writer_in_flight():
+    with Runtime(RuntimeConfig(memory_capacity=1 << 28)) as rt:
+        x = _object(rt, "device")
+        gate = threading.Event()
+        _held_writer(rt, x, gate)
+        out = np.zeros_like(DATA)
+        reader = threading.Thread(target=x.get, kwargs={"out": out})
+        reader.start()
+        reader.join(0.3)
+        assert reader.is_alive()        # waiting for the writer
+        gate.set()
+        reader.join(10)
+        assert not reader.is_alive()
+        np.testing.assert_array_equal(out, DATA * 2.0 + 2.0)
+
+
+def test_get_into_timeout_releases_its_pin():
+    with Runtime(RuntimeConfig(memory_capacity=1 << 28)) as rt:
+        x = _object(rt, "device")
+        gate = threading.Event()
+        _held_writer(rt, x, gate)
+        with pytest.raises(TimeoutError):
+            x.get(out=np.empty_like(DATA), timeout=0.05)
+        assert x.host_pins == 0
+        gate.set()
+        rt.barrier(timeout=10)
+        assert not rt.residency.pinned(x)   # the writer's pin alone was left
+
+
+def test_get_into_releases_its_pin_for_a_later_writer():
+    with Runtime(RuntimeConfig(memory_capacity=1 << 28)) as rt:
+        x = _object(rt, "device")
+        x.get(out=np.empty_like(DATA))
+        assert not rt.residency.pinned(x) and x.host_pins == 0
+        rt.run(lambda v: v - 1.0, [(x, "rw")])
+        rt.barrier(timeout=10)
+        np.testing.assert_array_equal(x.get(out=np.empty_like(DATA)),
+                                      DATA * 2.0)
 
 
 # ---------------------------------------------------------------------------
