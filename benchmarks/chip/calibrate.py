@@ -1,20 +1,22 @@
-"""The control's readings: the upper ones a cell's correctness limits are set
-from.
+"""The readings a cell's correctness limits are set from.
 
     python3 benchmarks/chip/calibrate.py --workload <config>.<traffic> \
         --seed N --seeds 3
 
 In one process, on this machine's chips and at the cell's own size, for each
-of ``--seeds`` seeds: the control, the reference computed in the precision
-below the configuration's, read as a run reads the program. The lower
-readings are the ``checked`` numbers of the cell's own runs (``run.py``).
-Prints one JSON line per reading and a summary line last.
+of ``--seeds`` seeds: the program's (lower) reading, one solve of the cell's
+traffic compared with the reference as a run compares its sampled solve;
+then the control's (upper), the reference computed in the precision below
+the configuration's, read as a run reads the program. The cell's own runs
+(``run.py``) add lower readings. Prints one JSON line per reading and a
+summary line last.
 """
 import time
 
 STARTED = time.perf_counter()
 
 import argparse  # noqa: E402
+import gc  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 
@@ -34,16 +36,28 @@ def main(argv=None) -> int:
     driver = harness.load_module(
         harness.HERE / "drivers" / f"{config['driver']}.py",
         f"bench_driver_{config['driver']}")
-    upper = {}
+    lower, upper = {}, {}
     for seed in range(args.seed, args.seed + args.seeds):
+        work = driver.Cell(config, traffic, seed)
         t = time.perf_counter()
-        read = driver.Cell(config, traffic, seed).control()
+        _, answer = work.solve(None)
+        read = {k: v for k, (v, _) in work.check(answer).items()}
+        del answer
+        gc.collect()
+        for k, v in read.items():
+            lower[k] = max(lower.get(k, v), v)
+        print(json.dumps({"program": seed, "seconds": time.perf_counter() - t,
+                          "checked": read}), flush=True)
+        t = time.perf_counter()
+        read = work.control()
+        del work
+        gc.collect()
         for k, v in read.items():
             upper[k] = min(upper.get(k, v), v)
         print(json.dumps({"control": seed, "seconds": time.perf_counter() - t,
                           "checked": read}), flush=True)
-    print(json.dumps({"workload": args.workload, "upper": upper,
-                      "limits": config["limits"],
+    print(json.dumps({"workload": args.workload, "lower": lower,
+                      "upper": upper, "limits": config["limits"],
                       "seconds": time.perf_counter() - STARTED}), flush=True)
     return 0
 

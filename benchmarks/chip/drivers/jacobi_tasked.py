@@ -40,6 +40,26 @@ def step_work(shape) -> tuple:
     return 2 * F32 * points, 7 * points
 
 
+def chunk_placement(rt) -> list:
+    """For each of the runtime's devices, in their order, the names of the
+    chunks whose device copy lives there, after checking that each recorded
+    copy really is on that device's chip."""
+    placed = []
+    for dev in rt.devices:
+        dev_id = dev.info.device_id
+        names = []
+        for obj in rt.residency.objects_on(dev_id):
+            if not obj.name.startswith("chunk"):
+                continue
+            arr = obj.copies[dev_id]
+            if arr.devices() != {dev.jax_device}:
+                raise AssertionError(f"{obj.name} is recorded on {dev_id} "
+                                     f"but lives on {arr.devices()}")
+            names.append(obj.name)
+        placed.append(sorted(names))
+    return placed
+
+
 class Cell:
     def __init__(self, config: dict, traffic: dict, seed: int):
         if config["dtype"] != "float32":
@@ -71,6 +91,8 @@ class Cell:
                                  over_decomposition=self.od)
                 after = rt.stats()
                 probe.count({k: after[k] - before[k] for k in COUNTERS})
+                if probe.traced:
+                    probe.note("placement", chunk_placement(rt))
             finally:
                 with probe.span("runtime_shutdown"):
                     rt.shutdown()

@@ -1,7 +1,9 @@
 """The application's own host copies per iteration: ``run_tasked``'s
-``jacobi.split`` (the chunk copies and the objects made from them) and
-``jacobi.assemble`` (the output put together from the chunks) spans, over
-the iterations of the window."""
+``jacobi.split`` spans (the chunk copies and the objects made from them),
+over the iterations of the window. A program whose ``run_tasked`` still
+copies its output together from the chunks has ``jacobi.assemble`` spans
+too, and they are added; ``run_tasked`` as it stands downloads each
+chunk into the output."""
 from program_trace import span_total
 
 

@@ -1,6 +1,8 @@
 """Device-to-host rate of the transfer engine: the bytes the runtime counted
-down (``bytes_d2h``) over the time in its ``rt.d2h`` spans (the download
-into pooled staging buffers), in GB/s."""
+down (``bytes_d2h``) over the time in its ``rt.d2h`` spans, in GB/s. Under
+``run_tasked`` each chunk comes down straight into its block of the
+caller's output (``get(out=...)``); a plain ``get()`` downloads into a
+pooled staging buffer."""
 from program_trace import span_total
 
 
