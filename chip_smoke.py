@@ -6,9 +6,9 @@ points a user calls.
 
 One chip: the tasking runtime running Jacobi3D (512³ f32, over-decomposed
 4 ways, 10 iterations) against the reference, the same run replayed as
-compiled task graphs, the Pallas stencil compiled for the chip, the
-distributed layer (two in-process ranks on the one chip), and phi4-mini
-serving at its published width with random weights. Four chips: the
+compiled task graphs, the Pallas stencil compiled for the chip against the
+jnp one, the distributed layer (two in-process ranks on the one chip), and
+phi4-mini serving at its published width with random weights. Four chips: the
 runtime over all four chips and the SPMD path on a four-chip mesh, both
 against the one-chip reference.
 
@@ -98,26 +98,26 @@ def phase_tasked(u0, want, iters, od, trace_graphs=False, n_devices=1):
     return info
 
 
-def phase_pallas(u0):
+def phase_pallas(u0, seed):
     """One sweep of the Pallas stencil, compiled for the chip, against the
-    jnp stencil run_tasked launches."""
-    from repro.apps.jacobi3d import stencil_update
+    jnp stencil, with every face of the chunk non-zero."""
+    from repro.apps.jacobi3d import stencil_jnp
     from repro.kernels import ops
+    n0, n1, n2 = u0.shape
+    rng = np.random.default_rng(seed)
     u = jnp.asarray(u0)
-    u_pad = jnp.pad(u, 1)
+    faces = [jnp.asarray(rng.random(s, dtype=np.float32))
+             for s in ((n1, n2), (n1, n2), (n0, n2), (n0, n2), (n0, n1),
+                       (n0, n1))]
     t0 = time.perf_counter()
-    compiled = jax.jit(ops.jacobi3d).lower(u_pad).compile()
+    compiled = jax.jit(ops.jacobi3d).lower(u, *faces).compile()
     compile_s = time.perf_counter() - t0
     if "tpu_custom_call" not in compiled.as_text():
         raise AssertionError("the stencil was not compiled as a TPU kernel")
     t0 = time.perf_counter()
-    got = np.asarray(jax.block_until_ready(compiled(u_pad)))
+    got = np.asarray(jax.block_until_ready(compiled(u, *faces)))
     run_s = time.perf_counter() - t0
-    z = jnp.zeros
-    n0, n1, n2 = u.shape
-    want = np.asarray(jax.jit(stencil_update)(
-        u, z((n1, n2)), z((n1, n2)), z((n0, n2)), z((n0, n2)),
-        z((n0, n1)), z((n0, n1))))
+    want = np.asarray(jax.jit(stencil_jnp)(u, *faces))
     np.testing.assert_allclose(got, want, rtol=RTOL, atol=ATOL)
     return {"compile_seconds": compile_s, "first_run_seconds": run_s,
             "tpu_custom_call": True, "max_abs_err": max_err(got, want)}
@@ -225,7 +225,7 @@ def one_chip(seed: int) -> None:
     run_phase("jacobi_tasked", phase_tasked, u0, want, iters, od)
     run_phase("jacobi_replay", phase_tasked, u0, want, iters, od,
               trace_graphs=True)
-    run_phase("pallas_stencil", phase_pallas, u0)
+    run_phase("pallas_stencil", phase_pallas, u0, seed)
     del want
     n_c, iters_c = 256, 10
     u0 = grid(n_c, seed)

@@ -27,7 +27,8 @@ Four execution modes on the same numerics:
                     (exchange, barrier, then compute), ``False`` lets XLA
                     overlap per-slab compute with the next face transfer.
 
-The stencil itself also exists as a Pallas kernel (repro.kernels.jacobi3d).
+Every mode but the reference sweeps through ``stencil_update``: on a TPU the
+Pallas stencil (repro.kernels.jacobi3d), elsewhere the jnp one.
 """
 from __future__ import annotations
 
@@ -47,11 +48,29 @@ from repro.core import HeteroTask, Runtime
 from repro.distributed.collectives import halo_exchange_1d
 from repro.distributed.handlers import handler
 from repro.distributed.overdecomp import DecompPlan, plan_decomposition
+from repro.kernels.jacobi3d import jacobi3d, supports
 
 
 def stencil_update(u: jax.Array, lo0, hi0, lo1, hi1, lo2, hi2) -> jax.Array:
     """One Jacobi sweep over the interior given face halos (each a slab of
-    thickness 1; zeros at physical boundaries)."""
+    thickness 1; zeros at physical boundaries).
+
+    On a TPU a chunk the Pallas stencil ``supports`` (float32, ``Y % 8 ==
+    0``, ``Z % 128 == 0``, eight planes within its VMEM budget) runs it,
+    reading the chunk and its faces in place; anything else, and every other
+    platform, runs ``stencil_jnp``."""
+    faces = (lo0, hi0, lo1, hi1, lo2, hi2)
+    if not (supports(u.shape, u.dtype)
+            and all(f.dtype == u.dtype for f in faces)):
+        return stencil_jnp(u, *faces)
+    return jax.lax.platform_dependent(
+        u, *faces, tpu=functools.partial(jacobi3d, interpret=False),
+        default=stencil_jnp)
+
+
+def stencil_jnp(u: jax.Array, lo0, hi0, lo1, hi1, lo2, hi2) -> jax.Array:
+    """``stencil_update`` in plain jnp: the chunk padded by its faces, then
+    the six shifted views summed."""
     up = jnp.pad(u, 1)
     up = up.at[0, 1:-1, 1:-1].set(lo0).at[-1, 1:-1, 1:-1].set(hi0)
     up = up.at[1:-1, 0, 1:-1].set(lo1).at[1:-1, -1, 1:-1].set(hi1)
@@ -71,7 +90,7 @@ def run_reference(u0: np.ndarray, iters: int) -> np.ndarray:
     @jax.jit
     def step(u):
         z = jnp.zeros
-        return stencil_update(
+        return stencil_jnp(
             u,
             z(u.shape[1:]), z(u.shape[1:]),
             z((u.shape[0], u.shape[2])), z((u.shape[0], u.shape[2])),

@@ -26,9 +26,9 @@ def matmul(a, b, **kw):
     return _matmul(a, b, **kw)
 
 
-def jacobi3d(u_pad, **kw):
+def jacobi3d(u, lo0, hi0, lo1, hi1, lo2, hi2, **kw):
     kw.setdefault("interpret", _default_interpret())
-    return _jacobi3d(u_pad, **kw)
+    return _jacobi3d(u, lo0, hi0, lo1, hi1, lo2, hi2, **kw)
 
 
 def ssd_chunk(x, dt, A, B, C, **kw):

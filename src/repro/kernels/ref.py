@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel (allclose targets for tests)."""
+"""Pure-jnp oracles for the Pallas kernels (allclose targets for tests); the
+Jacobi stencil's is ``apps/jacobi3d.stencil_jnp``, the path it replaces."""
 from __future__ import annotations
 
 import jax
@@ -8,14 +9,6 @@ import jax.numpy as jnp
 def matmul_ref(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32)
                    ).astype(a.dtype)
-
-
-def jacobi3d_ref(u_pad: jax.Array) -> jax.Array:
-    """u_pad: [X+2, Y+2, Z+2] → interior update [X, Y, Z]."""
-    return ((u_pad[:-2, 1:-1, 1:-1] + u_pad[2:, 1:-1, 1:-1] +
-             u_pad[1:-1, :-2, 1:-1] + u_pad[1:-1, 2:, 1:-1] +
-             u_pad[1:-1, 1:-1, :-2] + u_pad[1:-1, 1:-1, 2:]) / 6.0
-            ).astype(u_pad.dtype)
 
 
 def ssd_chunk_ref(x, dt, A, B, C):

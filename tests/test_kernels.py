@@ -38,14 +38,68 @@ def test_matmul_property(mt, kt, nt):
                                rtol=1e-3, atol=1e-3)
 
 
-@pytest.mark.parametrize("shape,bx", [((18, 10, 12), 4), ((34, 18, 18), 8),
-                                      ((10, 34, 6), 8)])
+FACES = ("lo0", "hi0", "lo1", "hi1", "lo2", "hi2")
+
+
+def _chunk(shape, key, nonzero=("u",) + FACES):
+    """The chunk ``u`` and its six faces, uniform in [0, 1) where named in
+    ``nonzero`` and zero elsewhere."""
+    x, y, z = shape
+    shapes = dict(u=shape, lo0=(y, z), hi0=(y, z), lo1=(x, z), hi1=(x, z),
+                  lo2=(x, y), hi2=(x, y))
+    return [jax.random.uniform(jax.random.fold_in(key, i), shapes[name])
+            if name in nonzero else jnp.zeros(shapes[name], jnp.float32)
+            for i, name in enumerate(("u",) + FACES)]
+
+
+@pytest.mark.parametrize("shape,bx", [((8, 8, 128), 8), ((16, 16, 256), 8),
+                                      ((20, 264, 128), 8)])
 def test_jacobi3d_kernel(shape, bx):
-    u = jax.random.normal(KEY, shape, jnp.float32)
-    got = ops.jacobi3d(u, bx=bx)
-    want = ref.jacobi3d_ref(u)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    """The face-fed kernel against the jnp stencil with every face non-zero;
+    (20, 264, 128) ends in a partial block of 4 planes and sweeps each plane
+    in three tiles of rows."""
+    from repro.apps.jacobi3d import stencil_jnp
+    args = _chunk(shape, KEY)
+    got = ops.jacobi3d(*args, bx=bx)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(stencil_jnp(*args)),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("face", FACES)
+def test_jacobi3d_kernel_one_face(face):
+    """Each face alone, on a zero chunk: its values land on its own boundary
+    plane only."""
+    from repro.apps.jacobi3d import stencil_jnp
+    args = _chunk((16, 16, 256), KEY, nonzero=(face,))
+    got = np.asarray(ops.jacobi3d(*args))
+    want = np.asarray(stencil_jnp(*args))
+    assert np.count_nonzero(want) == args[1 + FACES.index(face)].size
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("shape,kernel", [((16, 16, 256), True),
+                                          ((10, 34, 6), False),
+                                          ((8, 1024, 1024), False)])
+def test_stencil_update_picks_its_path_by_shape(shape, kernel):
+    """stencil_update carries the Pallas stencil (for a TPU) only for
+    chunks whose rows fill whole (8, 128) tiles and whose blocks of 8 planes
+    fit VMEM; an unaligned chunk or a 1024² plane runs the jnp body, and
+    both match a plain numpy sweep here."""
+    from repro.apps.jacobi3d import stencil_update
+    args = _chunk(shape, KEY)
+    assert ("pallas_call" in str(jax.make_jaxpr(stencil_update)(*args))
+            ) == kernel
+    u, lo0, hi0, lo1, hi1, lo2, hi2 = (np.asarray(a, np.float64)
+                                       for a in args)
+    p = np.pad(u, 1)
+    p[0, 1:-1, 1:-1], p[-1, 1:-1, 1:-1] = lo0, hi0
+    p[1:-1, 0, 1:-1], p[1:-1, -1, 1:-1] = lo1, hi1
+    p[1:-1, 1:-1, 0], p[1:-1, 1:-1, -1] = lo2, hi2
+    want = (p[:-2, 1:-1, 1:-1] + p[2:, 1:-1, 1:-1] + p[1:-1, :-2, 1:-1] +
+            p[1:-1, 2:, 1:-1] + p[1:-1, 1:-1, :-2] + p[1:-1, 1:-1, 2:]) / 6
+    np.testing.assert_allclose(np.asarray(stencil_update(*args)), want,
+                               rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("bc,q,h,p,n", [(2, 16, 4, 8, 16), (1, 32, 2, 16, 8),

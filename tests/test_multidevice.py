@@ -95,14 +95,17 @@ def test_moe_ep_matches_dense_oracle():
     """, devices=8)
 
 
-def test_spmd_jacobi_multidevice():
-    run_py("""
+@pytest.mark.parametrize("shape", [(16, 8, 8), (16, 8, 128)])
+def test_spmd_jacobi_multidevice(shape):
+    """(16, 8, 128) is a grid whose slabs the Pallas stencil takes: its TPU
+    body is traced beside the jnp one inside shard_map."""
+    run_py(f"""
         import numpy as np, jax
         from repro.apps.jacobi3d import run_reference, run_spmd
         from repro.launch.mesh import make_mesh
         mesh = make_mesh((4, 1), ('data', 'model'))
         rng = np.random.default_rng(0)
-        u0 = rng.random((16, 8, 8)).astype(np.float32)
+        u0 = rng.random({shape}).astype(np.float32)
         want = run_reference(u0, 3)
         for bulk in (False, True):
             got = run_spmd(u0, 3, mesh, axis='data', bulk_sync=bulk)

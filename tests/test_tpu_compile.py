@@ -8,15 +8,18 @@ worker given this file loads the TPU library.
 """
 import dataclasses
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -34,10 +37,15 @@ def one_chip():
             # left set on this thread (the chip refuses f32 contraction of
             # bf16 operands)
             with jax.default_matmul_precision(None):
-                yield SingleDeviceSharding(topo.devices[0])
+                yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", cache_was)
             cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -45,11 +53,28 @@ def _compile(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _chunk_shapes(x, y, z):
+    f32 = jnp.float32
+    return (((x, y, z), f32), ((y, z), f32), ((y, z), f32), ((x, z), f32),
+            ((x, z), f32), ((x, y), f32), ((x, y), f32))
+
+
 def test_jacobi3d_kernel_compiles_at_512(one_chip):
     from repro.kernels.jacobi3d import jacobi3d
-    n = 512
     c = _compile(functools.partial(jacobi3d, interpret=False), one_chip,
-                 ((n + 2,) * 3, jnp.float32))
+                 *_chunk_shapes(512, 512, 512))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_spmd_step_compiles_on_four_chips(topo):
+    """run_spmd's step on a 512³ grid over a 2x2 mesh: each chip's slab goes
+    through the Pallas stencil inside shard_map."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+    from repro.apps.jacobi3d import make_spmd_step
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    grid = jax.ShapeDtypeStruct((512,) * 3, jnp.float32,
+                                sharding=NamedSharding(mesh, PS("data")))
+    c = make_spmd_step(mesh).lower(grid).compile()
     assert "tpu_custom_call" in c.as_text()
 
 
@@ -69,16 +94,20 @@ def test_flash_attention_kernel_compiles(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_stencil_update_compiles_on_a_chunk(one_chip):
-    """The jnp stencil ``run_tasked`` launches, on one chunk of a 512³ grid
-    over-decomposed four ways."""
+@pytest.mark.parametrize("chunk", [(512, 512, 1024), (256, 256, 256)])
+def test_stencil_update_compiles_on_a_chunk(one_chip, chunk):
+    """The update ``run_tasked`` launches, on a chunk of a 1024³ grid
+    over-decomposed 4 and 64 ways: one Pallas kernel, with no padded copy
+    of the chunk and no halo written into one."""
     from repro.apps.jacobi3d import stencil_update
-    x, y, z = 128, 512, 512
-    f32 = jnp.float32
-    c = _compile(stencil_update, one_chip, ((x, y, z), f32),
-                 ((y, z), f32), ((y, z), f32), ((x, z), f32), ((x, z), f32),
-                 ((x, y), f32), ((x, y), f32))
-    assert c.memory_analysis().output_size_in_bytes == x * y * z * 4
+    c = _compile(stencil_update, one_chip, *_chunk_shapes(*chunk))
+    hlo = c.as_text()
+    assert "tpu_custom_call" in hlo
+    assert not re.search(r"\b(pad|dynamic-update-slice)\(", hlo)
+    m = c.memory_analysis()
+    assert m.output_size_in_bytes == 4 * math.prod(chunk)
+    # the padded form needs 1,231,836,160 B of temporaries at od4's chunk
+    assert m.temp_size_in_bytes < 64 << 20
 
 
 def test_expert_share_compiles_at_granite_widths(one_chip):
