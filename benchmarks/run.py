@@ -2,11 +2,11 @@
 
 Prints ``name,us_per_call,derived`` CSV rows (stdout), mirroring:
   Fig. 8   tasking-framework optimization ladder (tasking_overhead)
-  Fig. 9   multi-device scaling (multidevice_scaling)
   Fig. 10–12  ping-pong latency/bandwidth (pingpong)
-  Fig. 13/15  Jacobi3D scaling + over-decomposition (jacobi_scaling)
-plus a summary of the multi-pod dry-run + roofline table (reads the JSONs
-produced by benchmarks/run_dryrun_sweep.py — run that first for fresh data).
+plus a report of the runtime-ladder JSONs that
+``benchmarks/run_dryrun_sweep.py --rt-ladder`` writes (run that first for
+fresh data). All of it runs on the CPU; the chip benchmark is
+``benchmarks/chip/``.
 
 Each section runs in a child process of its own and this process never
 imports JAX: a chip belongs to one process at a time, and a section that
@@ -29,9 +29,7 @@ def _section(title):
 def main() -> None:
     sections = [
         ("fig8 tasking overhead ladder", "tasking_overhead"),
-        ("fig9 multi-device scaling", "multidevice_scaling"),
         ("fig10-12 pingpong", "pingpong"),
-        ("fig13/15 jacobi scaling + over-decomposition", "jacobi_scaling"),
     ]
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
@@ -49,31 +47,10 @@ def main() -> None:
             failures.append(title)
             print(f"SECTION_FAILED {title}: exit code {rc}", flush=True)
 
-    _section("dry-run / roofline summary")
-    result_files = sorted(glob.glob(os.path.join(HERE, "results", "dryrun",
-                                                 "*.json")))
-    for f in result_files:
-        if os.path.basename(f).startswith("rt_ladder__"):
-            continue           # runtime-ladder payloads summarized below
-        with open(f) as fh:
-            d = json.load(fh)
-        if not isinstance(d, dict):
-            continue
-        if d.get("probe") is not None or d.get("skipped"):
-            continue
-        if "error" in d:
-            print(f"dryrun_{os.path.basename(f)},,ERROR")
-            continue
-        pods = "pod2" if "pod" in d.get("mesh", {}) else "pod1"
-        print(f"dryrun_{d['arch']}__{d['shape']}__{pods},"
-              f"{d.get('compile_s', '')},"
-              f"bottleneck={d.get('bottleneck')};chips={d.get('chips')}")
-
     _section("runtime ladder / residency report")
-    for f in result_files:
+    for f in sorted(glob.glob(os.path.join(HERE, "results", "dryrun",
+                                           "rt_ladder__*.json"))):
         base = os.path.basename(f)
-        if not base.startswith("rt_ladder__"):
-            continue
         with open(f) as fh:
             d = json.load(fh)
         tag = base[len("rt_ladder__"):-len(".json")]

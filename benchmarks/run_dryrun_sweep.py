@@ -60,15 +60,11 @@ def _run_subprocess_cell(tag, cmd, env, meta, timeout):
         print(f"TIME {tag}", flush=True)
 
 
-def run_cell(arch, shape, multi_pod, opt_level, timeout=3600, probe=None):
+def run_cell(arch, shape, multi_pod, opt_level, timeout=3600):
     tag = f"{arch}__{shape}__{'pod2' if multi_pod else 'pod1'}__{opt_level}"
-    if probe is not None:
-        tag += f"__probe{probe}"
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
            "--shape", shape, "--opt-level", opt_level, "--out",
            os.path.join(OUT_DIR, tag + ".json")]
-    if probe is not None:
-        cmd += ["--probe", str(probe)]
     if multi_pod:
         cmd.append("--multi-pod")
     env = dict(os.environ)
@@ -108,8 +104,6 @@ def main():
     ap.add_argument("--only-multi-pod", action="store_true")
     ap.add_argument("--opt-level", default="baseline")
     ap.add_argument("--arch", default=None)
-    ap.add_argument("--probes", action="store_true",
-                    help="also run 0-layer/1-period probe lowerings")
     ap.add_argument("--rt-ladder", action="store_true",
                     help="also sweep the tasking-runtime ladder "
                          "(TF-Baseline … TF-Prefetch, TF-D2D)")
@@ -135,9 +129,6 @@ def main():
     for arch, shape in todo:
         if not args.only_multi_pod:
             run_cell(arch, shape, False, args.opt_level)
-            if args.probes:
-                run_cell(arch, shape, False, args.opt_level, probe=0)
-                run_cell(arch, shape, False, args.opt_level, probe=1)
         if args.multi_pod or args.only_multi_pod:
             run_cell(arch, shape, True, args.opt_level)
     print("sweep done", flush=True)

@@ -57,14 +57,15 @@ into the caller's array instead, and keeps no host copy
 staging buffers return to the pool when a host copy is dropped, transfer
 futures return to the ``RequestPool`` once consumed.
 
-Configuration toggles map 1:1 to the paper's optimization ladder (Fig. 8)
-so the benchmark can reproduce it:
+These configuration fields reproduce the paper's optimization ladder
+(Fig. 8, ``benchmarks/tasking_overhead.py`` sets them both ways):
   staging_pool     — §4.1.1 page-locked host memory pool
   cache_jit        — §4.1.2 custom device allocator (jit cache + donation)
   request_pool     — §4.1.4 request pools
   transfer_thread  — §4.1.3 dedicated transfer queues (one per device)
   inflight         — §4.1.3 multiple compute queues (async window)
   dedicated_threads— §4.1.6 one worker per device
+  sync_dispatch    — the TF-Baseline rung: block after every launch
   prefetch         — §4.1.3 transfer/compute overlap (argument pipeline)
   prefetch_depth   — §4.1.3 pipeline depth (tasks claimed ahead per worker)
   d2d              — §3.2.3 direct device-to-device transfers
@@ -99,6 +100,15 @@ from repro.core.topology import (InterconnectModel, probe_link,
                                  probe_runtime_links)
 
 
+# a worker that cannot launch (its async window is full, or nothing is
+# runnable) parks on the work condition for 20 of these before it looks
+# again; a push or a completion wakes it sooner
+POLL_INTERVAL_S = 0.0005
+# max producer-chain replay depth when coherence finds an object with no
+# valid replica anywhere (evicted-and-lost)
+LINEAGE_DEPTH = 4
+
+
 class InjectedTaskFault(RuntimeError):
     """Deterministic kernel fault planted by FaultInjector.fail_task."""
 
@@ -120,21 +130,17 @@ class RuntimeConfig:
     memory_capacity: Optional[int] = None
     # host uploads and downloads above this size move in pieces of it
     staging_chunk_bytes: int = 8 << 20
-    poll_interval_s: float = 0.0005
     # -- interconnect topology / message protocol (paper §3.2.3 + §4.2) --
     topology_probe: bool = True   # startup micro-probe seeds the model
-    topology_probe_bytes: int = 64 << 10
     # device pairs the startup host+ring probe did not cover are probed
     # lazily, once, on their first real transfer (ROADMAP follow-up c)
     lazy_probe: bool = True
     # distributed messages above this size switch from the eager
     # (monolithic) protocol to chunk-streamed rendezvous
     eager_threshold: int = 64 << 10
-    # rendezvous chunk size targets this many ms per chunk at the
-    # measured link bandwidth (bandwidth-delay-product sizing; several ms
-    # per chunk keeps fixed per-chunk dispatch cost amortized);
-    # chunk_bytes pins an explicit size instead (tests/benchmarks)
-    chunk_target_ms: float = 4.0
+    # rendezvous chunks are sized from the measured link bandwidth
+    # (messaging.CHUNK_TARGET_MS); chunk_bytes pins an explicit size
+    # instead (tests/benchmarks)
     chunk_bytes: Optional[int] = None
     # rendezvous sliding window: how many chunks the receiver lets the
     # sender keep in flight per stream (credit-based flow control). None
@@ -155,21 +161,12 @@ class RuntimeConfig:
     # controller declares a rank dead after timeout without one
     heartbeat_interval_s: float = 0.05
     heartbeat_timeout_s: float = 0.5
-    # reliability layer (engaged by Cluster.fault_injector): eager
-    # messages, RTS announcements and stream tails are retransmitted with
-    # exponential backoff up to send_retries attempts before the send is
-    # counted failed; receivers NACK stalled rendezvous streams on the
-    # same backoff schedule
-    send_retries: int = 5
+    # reliability layer (engaged by Cluster.fault_injector): the first
+    # retransmit backoff and the pump's retry-check cadence; the attempt
+    # cap and backoff growth are messaging.SEND_RETRIES /
+    # messaging.RETRY_BACKOFF_MULT
     retry_backoff_s: float = 0.05
-    retry_backoff_mult: float = 2.0
     retry_tick_s: float = 0.005
-    # protocol timeouts (formerly hardcoded): tail-upload wait when a
-    # rendezvous stream completes, the peer-removal sweep's net-send
-    # rendezvous, and the pump-thread join at shutdown
-    rdzv_finish_timeout_s: float = 120.0
-    peer_sweep_timeout_s: float = 10.0
-    pump_join_timeout_s: float = 5.0
     # -- compiled task-graph fast path (core/taskgraph.py) --
     # trace recurring submit windows (delimited by step_boundary()/
     # barrier()) and, once the same DAG recurred replay_after times,
@@ -189,14 +186,6 @@ class RuntimeConfig:
     # treated as never-arrived (the reliability layer retransmits), so
     # corruption surfaces as a retry — never a hang or a wrong answer
     verify_payloads: bool = True
-    # ckpt_digest: per-leaf content digests in checkpoint manifests,
-    # verified by restore/restore_leaf (Checkpointer honors this default
-    # unless its own ctor argument overrides it)
-    ckpt_digest: bool = True
-    # lineage_depth: max producer-chain replay depth when coherence finds
-    # an object with no valid replica anywhere (evicted-and-lost). 0
-    # disables the lineage ledger entirely.
-    lineage_depth: int = 4
     # task_retries: relaunch budget for a task whose kernel launch raised
     # (injected kernel faults, transient device errors) before the error
     # surfaces on the task future / strict barrier
@@ -208,15 +197,6 @@ class RuntimeConfig:
     # by default — below it every ring hop would be an eager message
     # anyway, so the ring's pipelining buys nothing
     coll_ring_cutover_bytes: int = 64 << 10
-    # cap on the credit window of op="reduce" rendezvous streams: every
-    # in-flight reduce chunk is a fused add pending on the consumer
-    # device's transfer lane, so this bounds accumulator-side device
-    # work/memory independently of the AIMD ceiling. 0 = uncapped
-    coll_max_inflight_chunks: int = 4
-    # collective tag namespace: tags (which scope every stream and
-    # handler invocation to one collective op) wrap at this size, so at
-    # most this many collectives may be in flight per group at once
-    coll_tag_space: int = 1 << 12
     # -- concurrency sanitizer (core/sanitizer.py) --
     # sanitize: install the process-global RuntimeSanitizer before this
     # runtime builds its locks — lock-order tracking, lane-discipline
@@ -224,9 +204,6 @@ class RuntimeConfig:
     # assertions at Rank shutdown. Defaults on when REPRO_SANITIZE=1
     # (the CI sanitize shard sets only the env var)
     sanitize: bool = dataclasses.field(default_factory=sanitizer.env_enabled)
-    # contended-lock threshold: a tracked-lock acquire that waits at
-    # least this long on a strict lane counts as a lane-blocking event
-    sanitize_block_s: float = 0.010
 
 
 class Runtime:
@@ -236,7 +213,7 @@ class Runtime:
         if self.cfg.sanitize:
             # must precede every lock construction below: the factories
             # consult the global sanitizer at creation time
-            sanitizer.install(self.cfg.sanitize_block_s)
+            sanitizer.install()
         self.devices: List[Device] = devices if devices is not None else \
             discover_devices(self.cfg.memory_capacity, self.cfg.cache_jit)
         for d in self.devices:
@@ -256,8 +233,7 @@ class Runtime:
         self.scheduler.bind_residency(self.residency)
         self.scheduler.bind_topology(self.topology)
         if self.cfg.topology_probe:
-            probe_runtime_links(self.topology, self.devices,
-                                self.cfg.topology_probe_bytes)
+            probe_runtime_links(self.topology, self.devices)
         self.staging = StagingPool(self.cfg.staging_pool)
         self.futures = RequestPool(HFuture, self.cfg.request_pool)
         self._lock = sanitizer.make_rlock("Runtime._lock")
@@ -274,8 +250,7 @@ class Runtime:
                        "task_retries": 0, "tasks_failed": 0,
                        "ready_wait_s": 0.0, "d2h_direct": 0}
         # lineage ledger: producer records for lost-replica recovery
-        self.lineage: Optional[LineageLedger] = (
-            LineageLedger() if self.cfg.lineage_depth > 0 else None)
+        self.lineage = LineageLedger()
         self._lineage_lock = sanitizer.make_rlock("Runtime._lineage_lock")
         self._recovering: set = set()       # cycle guard (object ids)
         self._failed_tasks: List[BaseException] = []
@@ -637,12 +612,11 @@ class Runtime:
             if HOST in obj.copies:
                 return obj.copies[HOST], None
             src = next(iter(obj.copies), None)
-        if src is None and self.lineage is not None:
-            if self._lineage_recover(obj):
-                with obj.lock:
-                    if HOST in obj.copies:
-                        return obj.copies[HOST], None
-                    src = next(iter(obj.copies), None)
+        if src is None and self._lineage_recover(obj):
+            with obj.lock:
+                if HOST in obj.copies:
+                    return obj.copies[HOST], None
+                src = next(iter(obj.copies), None)
         return None, src
 
     def _counted_download(self, obj: HeteroObject, src: int,
@@ -792,24 +766,17 @@ class Runtime:
         return jnp.concatenate(pieces, axis=0)
 
     # -- lineage-based recovery ----------------------------------------
-    def _lineage_recover(self, obj: HeteroObject,
-                         depth: Optional[int] = None) -> bool:
+    def _lineage_recover(self, obj: HeteroObject) -> bool:
         """Rebuild a lost object by replaying its recorded producer task.
 
-        Bounded by ``cfg.lineage_depth`` and cycle-safe: a record is only
+        Bounded by ``LINEAGE_DEPTH`` and cycle-safe: a record is only
         replayable when every input it *read* still sits at the exact
         generation it read (in-place ``rw`` chains therefore refuse to
         replay past their own overwrite), and a per-object guard set
         breaks any residual recursion. Serialised under one recursive
         lock so concurrent coherence walks don't double-recompute."""
-        if self.lineage is None:
-            return False
-        if depth is None:
-            depth = self.cfg.lineage_depth
-        if depth <= 0:
-            return False
         with self._lineage_lock:
-            return self._lineage_recover_locked(obj, depth)
+            return self._lineage_recover_locked(obj, LINEAGE_DEPTH)
 
     def _lineage_recover_locked(self, obj: HeteroObject, depth: int) -> bool:
         with obj.lock:
@@ -869,7 +836,7 @@ class Runtime:
                             self.residency.record(dev, oobj)
                 wi += 1
             self._stats["lineage_recomputes"] += 1
-            used = self.cfg.lineage_depth - depth + 1
+            used = LINEAGE_DEPTH - depth + 1
             if used > self._stats["recompute_depth_peak"]:
                 self._stats["recompute_depth_peak"] = used
             with obj.lock:
@@ -931,8 +898,7 @@ class Runtime:
                 self.topology.seed_from_path(src_dev, device_id)
                 try:
                     probe_link(self._device(src_dev),
-                               self._device(device_id), self.topology,
-                               self.cfg.topology_probe_bytes)
+                               self._device(device_id), self.topology)
                 except Exception:   # probe failure must never block data
                     pass
             self.residency.ensure_capacity(device_id, obj.nbytes,
@@ -1061,7 +1027,7 @@ class Runtime:
                     return
                 if async_mode and gate["n"] >= self.cfg.inflight:
                     with TraceAnnotation("rt.wait.inflight"):
-                        self._work.wait(timeout=self.cfg.poll_interval_s * 20)
+                        self._work.wait(timeout=POLL_INTERVAL_S * 20)
                     continue
             if staged:
                 task, dev, pmap = staged.popleft()
@@ -1080,7 +1046,7 @@ class Runtime:
                     if self._shutdown:
                         return
                     with TraceAnnotation("rt.wait.idle"):
-                        self._work.wait(timeout=self.cfg.poll_interval_s * 20)
+                        self._work.wait(timeout=POLL_INTERVAL_S * 20)
                 continue
             task, dev = item
             try:
@@ -1134,8 +1100,7 @@ class Runtime:
         overlapped = False
         # argument versions at launch time — the lineage record must pin
         # inputs to the generations this launch actually read
-        pre_gens = [ref.obj.generation for ref in task.args] \
-            if self.lineage is not None else None
+        pre_gens = [ref.obj.generation for ref in task.args]
         if prefetched is not None:
             # transfers were issued when the task was assigned; when they
             # completed during the previous task's compute the copy was
@@ -1192,7 +1157,7 @@ class Runtime:
                         ref.obj.generation += 1
                         self.residency.record(device_id, ref.obj)
                 wi += 1
-        if self.lineage is not None and wi:
+        if wi:
             seen_w: set = set()
             out_gens = {}
             for ref in task.args:

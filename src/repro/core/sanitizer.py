@@ -87,6 +87,9 @@ _LEAK_GAUGES = ("rdzv_out", "rdzv_in", "rdzv_bufs",
                 "pending_meta", "rdzv_sent", "unacked")
 
 _MAX_EVENTS = 100        # bounded lane-blocking event trace
+# contended-lock threshold: a tracked-lock acquire that waits at least
+# this long on a strict lane counts as a lane-blocking event
+BLOCK_THRESHOLD_S = 0.010
 
 
 def lane_blocking_ok(kind: str) -> bool:
@@ -241,7 +244,7 @@ class RuntimeSanitizer:
     event trace, and counters. Usable standalone in tests; the
     process-global instance is managed by ``install()``."""
 
-    def __init__(self, block_threshold_s: float = 0.010):
+    def __init__(self, block_threshold_s: float = BLOCK_THRESHOLD_S):
         self.block_threshold_s = block_threshold_s
         self._glock = threading.Lock()          # guards graph + events
         # (held_name, acquired_name) -> thread name of first observation
@@ -390,19 +393,14 @@ _SAN: Optional[RuntimeSanitizer] = None
 _install_lock = threading.Lock()
 
 
-def install(block_threshold_s: Optional[float] = None) -> RuntimeSanitizer:
+def install() -> RuntimeSanitizer:
     """Install (or return) the process-global sanitizer. Idempotent:
     lock identity must be stable across every Runtime/Rank in the
     process, so the first install wins."""
     global _SAN
     with _install_lock:
         if _SAN is None:
-            _SAN = RuntimeSanitizer(
-                block_threshold_s if block_threshold_s is not None
-                else 0.010)
-        elif block_threshold_s is not None:
-            _SAN.block_threshold_s = min(_SAN.block_threshold_s,
-                                         block_threshold_s)
+            _SAN = RuntimeSanitizer()
         return _SAN
 
 

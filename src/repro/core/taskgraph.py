@@ -442,8 +442,7 @@ class GraphTracer:
             # evictability.
             rt.residency.drop_many(dropped)
             rt.residency.record_many(written)
-            if rt.lineage is not None:
-                rt.lineage.forget_many(obj for _d, obj in written)
+            rt.lineage.forget_many(obj for _d, obj in written)
         except BaseException as e:
             self._retire_parked(parked, error=e)
             self._invalidate_locked()

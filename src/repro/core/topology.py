@@ -32,6 +32,8 @@ DEFAULT_LATENCY = 20e-6          # seconds
 # bandwidth term of such a transfer is noise (dispatch dominates).
 _LATENCY_SAMPLE_BYTES = 4 << 10
 _MIN_SECONDS = 1e-9
+# payload of each timed transfer in the startup and first-use probes
+TOPOLOGY_PROBE_BYTES = 64 << 10
 
 # adaptive credit-window controller (AIMD): the receiver's transfer-lane
 # queue depth and landing-slab occupancy arrive with every credit; a
@@ -343,7 +345,7 @@ class InterconnectModel:
 
 
 def probe_link(src_dev, dst_dev, model: InterconnectModel,
-               nbytes: int = 64 << 10) -> None:
+               nbytes: int = TOPOLOGY_PROBE_BYTES) -> None:
     """Lazy first-use micro-probe of one device pair (ROADMAP follow-up
     c): the startup probe covers host→device plus a device ring in O(n);
     any pair it skipped gets ONE timed ``nbytes`` transfer here, the
@@ -367,7 +369,7 @@ def probe_link(src_dev, dst_dev, model: InterconnectModel,
 
 
 def probe_runtime_links(model: InterconnectModel, devices,
-                        nbytes: int = 64 << 10) -> None:
+                        nbytes: int = TOPOLOGY_PROBE_BYTES) -> None:
     """Cheap startup micro-probe: one ``nbytes`` upload per device (host →
     device) and one ring hop per adjacent device pair (device → device,
     both directions), each timed and folded into ``model``. Ring, not

@@ -55,6 +55,11 @@ from repro.distributed.handlers import handler
 
 __all__ = ["CollectiveGroup", "CollectiveAborted"]
 
+# collective tag namespace: tags (which scope every stream and handler
+# invocation to one collective op) wrap at this size, so at most this
+# many collectives may be in flight per group at once
+COLL_TAG_SPACE = 1 << 12
+
 
 class CollectiveAborted(RuntimeError):
     """An in-flight collective was aborted by an elastic epoch bump; the
@@ -155,7 +160,6 @@ class CollectiveGroup:
         self.timeout_s = timeout_s
         cfg = cluster.ranks[self.members[0]].runtime.cfg
         self.cutover_bytes = cfg.coll_ring_cutover_bytes
-        self.tag_space = cfg.coll_tag_space
         by_node: Dict[Any, List[int]] = {}
         for m in self.members:
             by_node.setdefault(self.nodes[m], []).append(m)
@@ -186,11 +190,11 @@ class CollectiveGroup:
 
     def _new_op(self, kind: str) -> Dict[str, Any]:
         with self._lock:
-            tag = next(self._tag_counter) % self.tag_space
+            tag = next(self._tag_counter) % COLL_TAG_SPACE
             if tag in self._ops:
                 raise RuntimeError(
                     f"collective tag space exhausted: {len(self._ops)} "
-                    f"ops in flight with coll_tag_space={self.tag_space}")
+                    f"ops in flight with COLL_TAG_SPACE={COLL_TAG_SPACE}")
             op = {"tag": tag, "kind": kind, "epoch": self.epoch_fn(),
                   "done": threading.Event(), "err": None, "aborted": False,
                   "lock": sanitizer.make_lock("CollectiveGroup.op_lock"),
@@ -864,4 +868,4 @@ class CollectiveGroup:
                 "ring": list(self.ring),
                 "member_ring": list(self.ring_m),
                 "cutover_bytes": self.cutover_bytes,
-                "tag_space": self.tag_space}
+                "tag_space": COLL_TAG_SPACE}

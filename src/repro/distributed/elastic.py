@@ -368,10 +368,9 @@ class ElasticRuntime:
         self.epoch += 1
         for r in self.cluster.ranks:
             r.runtime.invalidate_traces()
-            if r.runtime.lineage is not None:
-                # records stay (generation checks keep them safe); new
-                # ones carry the new epoch for forensics
-                r.runtime.lineage.bump_epoch()
+            # lineage records stay (generation checks keep them safe);
+            # new ones carry the new epoch for forensics
+            r.runtime.lineage.bump_epoch()
 
     def _alive_ranks(self, exclude: Sequence[int] = ()) -> List[Any]:
         alive = set(self.controller.alive_workers()) - set(exclude)

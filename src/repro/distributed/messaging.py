@@ -103,6 +103,28 @@ INLINE_PAYLOAD_BYTES = 512
 # or a single unpipelined chunk
 MIN_CHUNK_BYTES = 64 << 10
 MAX_CHUNK_BYTES = 4 << 20
+# rendezvous chunk size targets this many ms per chunk at the measured
+# link bandwidth (bandwidth-delay-product sizing; several ms per chunk
+# keeps fixed per-chunk dispatch cost amortized)
+CHUNK_TARGET_MS = 4.0
+# reliability layer (engaged by Cluster.fault_injector): eager messages,
+# RTS announcements and stream tails are retransmitted up to this many
+# times before the send is counted failed, and receivers NACK stalled
+# rendezvous streams as often; each backoff is RETRY_BACKOFF_MULT times
+# the one before (starting from RuntimeConfig.retry_backoff_s)
+SEND_RETRIES = 5
+RETRY_BACKOFF_MULT = 2.0
+# protocol timeouts: tail-upload wait when a rendezvous stream completes,
+# the peer-removal sweep's net-send rendezvous, and the pump-thread join
+# at shutdown
+RDZV_FINISH_TIMEOUT_S = 120.0
+PEER_SWEEP_TIMEOUT_S = 10.0
+PUMP_JOIN_TIMEOUT_S = 5.0
+# cap on the credit window of op="reduce" rendezvous streams: every
+# in-flight reduce chunk is a fused add pending on the consumer device's
+# transfer lane, so this bounds accumulator-side device work and memory
+# independently of the AIMD ceiling
+COLL_MAX_INFLIGHT_CHUNKS = 4
 _msg_ids = itertools.count()
 _FLUSH = object()            # pump wake-up sentinel (not a Message)
 
@@ -434,7 +456,7 @@ class Rank:
         network receive overlaps chunk k's reduction; the finished slab
         rebinds as the target's only valid copy. Small payloads add on
         the receiver's host copy. The in-flight chunk window is capped by
-        ``RuntimeConfig.coll_max_inflight_chunks`` on top of the AIMD
+        ``COLL_MAX_INFLIGHT_CHUNKS`` on top of the AIMD
         controller. A ``reduce_into`` against an unregistered key is
         dropped on the receiver (aborted collective): the stream still
         completes and acks, nothing is mutated."""
@@ -620,7 +642,7 @@ class Rank:
 
     def _retry_unacked(self, now: float) -> None:
         """Retransmit reliable sends whose ack is overdue, with
-        exponential backoff; a send that exhausts ``send_retries`` is
+        exponential backoff; a send that exhausts ``SEND_RETRIES`` is
         abandoned and counted in ``send_failures`` (the elastic layer —
         not the transport — decides what a persistent failure means)."""
         cfg = self.runtime.cfg
@@ -631,12 +653,12 @@ class Rank:
             if now < st["deadline"]:
                 continue
             st["attempts"] += 1
-            if st["attempts"] > cfg.send_retries:
+            if st["attempts"] > SEND_RETRIES:
                 gone.append(mid)
                 self.stats["send_failures"] += 1
                 continue
             st["deadline"] = now + cfg.retry_backoff_s \
-                * (cfg.retry_backoff_mult ** st["attempts"])
+                * (RETRY_BACKOFF_MULT ** st["attempts"])
             self.stats["retries"] += 1
             for m in st["msgs"]:
                 self.cluster.deliver(m)
@@ -656,7 +678,7 @@ class Rank:
             if now < st["deadline"]:
                 continue
             st["attempts"] += 1
-            if st["attempts"] > cfg.send_retries:
+            if st["attempts"] > SEND_RETRIES:
                 self._rdzv_sent.pop(mid, None)
                 parked = self._rdzv_bufs.pop(mid, None)
                 if parked is not None:
@@ -664,7 +686,7 @@ class Rank:
                 self.stats["send_failures"] += 1
                 continue
             st["deadline"] = now + cfg.retry_backoff_s \
-                * (cfg.retry_backoff_mult ** st["attempts"])
+                * (RETRY_BACKOFF_MULT ** st["attempts"])
             meta, flat, elems = st["meta"], st["flat"], st["elems"]
             k = meta.nchunks - 1
             self.stats["retries"] += 1
@@ -687,12 +709,12 @@ class Rank:
             if st["arrived"] >= meta.nchunks:
                 continue
             nacks = st.get("nacks", 0)
-            backoff = cfg.retry_backoff_s * (cfg.retry_backoff_mult ** nacks)
+            backoff = cfg.retry_backoff_s * (RETRY_BACKOFF_MULT ** nacks)
             if now - st.get("last_progress", now) < backoff:
                 continue
             st["nacks"] = nacks + 1
             st["last_progress"] = now
-            if st["nacks"] > cfg.send_retries:
+            if st["nacks"] > SEND_RETRIES:
                 self._rdzv_in.pop(mid, None)
                 self.stats["send_failures"] += 1
                 continue
@@ -875,9 +897,8 @@ class Rank:
         are forwarded there), so no lock guards it."""
         chunk_b = self.runtime.cfg.chunk_bytes
         if chunk_b is None:
-            target_s = self.runtime.cfg.chunk_target_ms / 1e3
             chunk_b = self.cluster.topology.chunk_bytes(
-                self.rank, meta.dst, target_s,
+                self.rank, meta.dst, CHUNK_TARGET_MS / 1e3,
                 lo=MIN_CHUNK_BYTES, hi=MAX_CHUNK_BYTES)
         itemsize = np.dtype(meta.payload_dtype).itemsize
         elems = max(chunk_b // itemsize, 1)
@@ -1023,11 +1044,8 @@ class Rank:
             window = self.cluster.topology.window_chunks(
                 meta.src, self.rank, chunk_b,
                 queue_depth=rx_queue, slab_bytes=slab_bytes)
-        if meta.op == "reduce" and rt.cfg.coll_max_inflight_chunks:
-            # every in-flight reduce chunk is a pending fused add on the
-            # landing device's transfer lane: cap the pipeline depth so
-            # accumulator-side device work stays bounded (satellite knob)
-            window = min(window, rt.cfg.coll_max_inflight_chunks)
+        if meta.op == "reduce":
+            window = min(window, COLL_MAX_INFLIGHT_CHUNKS)
         window = max(1, min(window, meta.nchunks))
         state = {
             "meta": meta,
@@ -1118,9 +1136,8 @@ class Rank:
         target = self.cluster.topology.window_chunks(
             meta.src, self.rank, state["chunk_b"],
             queue_depth=q, slab_bytes=slab)
-        cap = self.runtime.cfg.coll_max_inflight_chunks
-        if state["reduce"] and cap:
-            target = min(target, cap)   # reduce pipeline stays bounded
+        if state["reduce"]:
+            target = min(target, COLL_MAX_INFLIGHT_CHUNKS)
         target = max(target, 1)
         if target != state["win"]:
             self.stats["window_adjusts"] += 1
@@ -1184,7 +1201,7 @@ class Rank:
                 base_fut = state["base_fut"]
                 state["base_fut"] = None
                 space, base = base_fut.get(
-                    timeout=rt.cfg.rdzv_finish_timeout_s)
+                    timeout=RDZV_FINISH_TIMEOUT_S)
                 rt.futures.release(base_fut)
                 if space == HOST:
                     base = np.asarray(base)
@@ -1258,7 +1275,7 @@ class Rank:
                 if seq != last_seq and fut.done():
                     self.stats["overlap_bytes"] += nb
             parts = []
-            timeout = self.runtime.cfg.rdzv_finish_timeout_s
+            timeout = RDZV_FINISH_TIMEOUT_S
             for k in range(meta.nchunks):
                 fut, _ = uploads[k]
                 try:
@@ -1590,7 +1607,7 @@ class Rank:
         ``_rdzv_out``/``_rdzv_bufs``), so it cannot race a concurrent
         ``_advance_stream``; the receive-side sweep runs here. Returns
         the per-kind swept counts."""
-        timeout = self.runtime.cfg.peer_sweep_timeout_s
+        timeout = PEER_SWEEP_TIMEOUT_S
         try:
             fut: HFuture = HFuture()
             self._net_send.submit(
@@ -1624,7 +1641,7 @@ class Rank:
     def shutdown(self):
         self._stop = True
         self.enqueue(None)
-        self._thread.join(timeout=self.runtime.cfg.pump_join_timeout_s)
+        self._thread.join(timeout=PUMP_JOIN_TIMEOUT_S)
         self.runtime.shutdown()
         # gauge hygiene (sanitizer): on a clean run every leak gauge must
         # have drained BEFORE the sweeps below reclaim stranded state —

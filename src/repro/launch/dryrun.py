@@ -3,19 +3,19 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # The two lines above MUST run before any other import (jax locks the device
 # count on first init). Everything else follows.
 import argparse
+import dataclasses
 import json
 import re
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import (SHAPES_BY_NAME, get_config, shapes_for, canon,
-                           ARCH_IDS)
-from repro.launch.mesh import (batch_specs, cache_specs, make_production_mesh,
-                               opt_specs, param_specs)
+from repro.configs import SHAPES_BY_NAME, canon, get_config, shapes_for
+from repro.launch.mesh import (cache_specs, make_production_mesh, opt_specs,
+                               param_specs)
 from repro.models import build_model
 from repro.models.layers import unbox
 from repro.models.model_zoo import Model
@@ -26,11 +26,6 @@ from repro.train.optimizer import TrainState, init_opt_state
 from repro.train.train_step import TrainConfig, make_train_step
 
 from jax.sharding import NamedSharding, PartitionSpec as PS
-
-# TPU v5e hardware constants (per chip) — see brief.
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
 
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
@@ -83,10 +78,10 @@ def parse_collective_bytes(hlo_text: str) -> Dict[str, int]:
     return out
 
 
-def _flags_for(shape_kind: str, seq_shard: bool, opt_level: str) -> Flags:
-    """opt_level 'baseline' = paper-faithful lowering; 'opt' = the winning
-    configuration from the §Perf hillclimb: full remat (lowest live memory)
-    + sequence parallelism + seq-sharded KV decode."""
+def _flags_for(seq_shard: bool) -> Flags:
+    """The lowering's model flags: full remat, expert-parallel MoE, and a
+    data-sharded KV cache when the decode batch does not divide the data
+    axis."""
     return Flags(
         remat="full",
         moe_mode="ep",
@@ -104,67 +99,18 @@ def _rules_for(opt_level: str) -> Dict[str, Any]:
     return {}
 
 
-# Named optimization stacks for §Perf hillclimbing. Each entry:
-# (extra_flags, extra_rules, over_decompose, cache_seq_axis)
-VARIANTS: Dict[str, Dict[str, Any]] = {
-    "baseline": {},
-    # paper-faithful technique: over-decomposition (microbatch pipeline)
-    "od2": dict(over_decompose=2),
-    "od4": dict(over_decompose=4),
-    "od8": dict(over_decompose=8),
-    # beyond-paper ladder
-    "dots": dict(extra_flags={"remat": "dots"}),
-    "dots_sp": dict(extra_flags={"remat": "dots"},
-                    extra_rules={"act_seq": "model"}),
-    "dots_sp_od4": dict(extra_flags={"remat": "dots"},
-                        extra_rules={"act_seq": "model"}, over_decompose=4),
-    "dots_sp_od8": dict(extra_flags={"remat": "dots"},
-                        extra_rules={"act_seq": "model"}, over_decompose=8),
-    # SP with full remat: bytes of SP + the low live-memory of full remat
-    "sp": dict(extra_rules={"act_seq": "model"}),
-    "sp_od4": dict(extra_rules={"act_seq": "model"}, over_decompose=4),
-    "sp_od8": dict(extra_rules={"act_seq": "model"}, over_decompose=8),
-    # decode: seq-sharded KV over the model axis (kv-head-replicated archs)
-    "kvseq_model": dict(extra_flags={"seq_shard_kv": "model"},
-                        cache_seq_axis="model"),
-    # mamba2: smaller SSD chunk (halves the decay-matrix traffic)
-    "ssd_chunk128": dict(ssd_chunk=128),
-    "ssd_chunk128_dots_sp": dict(ssd_chunk=128,
-                                 extra_flags={"remat": "dots"},
-                                 extra_rules={"act_seq": "model"}),
-    "loss_chunk512": dict(extra_flags={"loss_chunk": 512}),
-    # int8+EF compression of the cross-pod gradient reduction (use with
-    # --multi-pod; see train/compression.py). vocab replicated: the XLA SPMD
-    # partitioner CHECK-fails on a vocab-sharded embedding-grad scatter
-    # inside a partially-manual region (XLA limitation, see EXPERIMENTS.md)
-    "compress_pod": dict(train_compress=True, extra_rules={"vocab": None}),
-}
-
-
 def abstract_boxed(model: Model):
     boxed = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     return unbox(boxed)
 
 
 def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
-               opt_level: str = "baseline", over_decompose: int = 1,
-               extra_rules: Optional[Dict[str, Any]] = None,
-               extra_flags: Optional[Dict[str, Any]] = None,
-               probe: Optional[int] = None,
-               cache_seq_axis: Optional[str] = None,
-               ssd_chunk: Optional[int] = None,
-               train_compress: bool = False) -> Dict[str, Any]:
-    """probe=0: 0-layer model (scan/overhead-free baseline); probe=k: model
-    with exactly k periods. Used by launch.roofline to correct XLA's
-    count-scan-body-once cost accounting (see EXPERIMENTS.md §Method)."""
-    import dataclasses as _dc
+               opt_level: str = "baseline") -> Dict[str, Any]:
+    """Lower and compile one (arch × shape) step on the production mesh
+    and report what the compiled program shows: XLA's cost and memory
+    analyses, per-device collective bytes, HLO size, and the model's own
+    FLOPs per device."""
     cfg = get_config(arch)
-    if probe is not None:
-        period = len(cfg.layer_pattern)
-        cfg = _dc.replace(cfg, n_layers=probe * period,
-                          n_encoder_layers=(probe if cfg.enc_dec else 0))
-    if ssd_chunk is not None and cfg.ssm is not None:
-        cfg = _dc.replace(cfg, ssm=_dc.replace(cfg.ssm, chunk_size=ssd_chunk))
     shape = SHAPES_BY_NAME[shape_name]
     if shape not in shapes_for(cfg):
         return {"arch": arch, "shape": shape_name, "skipped": True,
@@ -173,20 +119,15 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     n_chips = int(np.prod(list(mesh.shape.values())))
     seq_shard = (shape.kind == "decode"
                  and shape.global_batch % mesh.shape["data"] != 0)
-    flags = _flags_for(shape.kind, seq_shard, opt_level)
+    flags = _flags_for(seq_shard)
+    cache_seq_axis = None
     if opt_level == "opt" and shape.kind == "decode" and not seq_shard \
             and cfg.n_kv_heads % mesh.shape.get("model", 1) != 0 \
             and not cfg.attention_free:
-        # hillclimb winner for kv-head-replicated GQA: seq-sharded KV cache
-        import dataclasses as _dc2
-        flags = _dc2.replace(flags, seq_shard_kv="model")
-        cache_seq_axis = cache_seq_axis or "model"
-    if extra_flags:
-        import dataclasses as _dc
-        flags = _dc.replace(flags, **extra_flags)
+        # kv-head-replicated GQA: shard the KV cache's sequence instead
+        flags = dataclasses.replace(flags, seq_shard_kv="model")
+        cache_seq_axis = "model"
     rules = _rules_for(opt_level)
-    if extra_rules:
-        rules.update(extra_rules)
     model = build_model(cfg, flags)
 
     def input_shardings(in_specs):
@@ -209,29 +150,11 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         in_specs = model.input_specs(shape)
         batch_shardings = input_shardings(in_specs)
         if shape.kind == "train":
-            compress = train_compress and "pod" in mesh.shape
-            n_pods = mesh.shape.get("pod", 1)
-
-            def mk_state(p):
-                ef = None
-                if compress:
-                    ef = jax.tree.map(
-                        lambda q: jnp.zeros((n_pods,) + q.shape, jnp.float32),
-                        p)
-                return TrainState(params=p, opt=init_opt_state(p), ef=ef)
-
-            state_abs = jax.eval_shape(mk_state, params_abs)
+            state_abs = jax.eval_shape(
+                lambda p: TrainState(params=p, opt=init_opt_state(p)),
+                params_abs)
             state_spec = opt_specs(state_abs, axes, mesh)
-            if compress:
-                ef_spec = jax.tree.map(
-                    lambda _: NamedSharding(mesh, PS("pod")),
-                    state_abs.ef)
-                import dataclasses as _dc3
-                state_spec = _dc3.replace(state_spec, ef=ef_spec)
-            step = make_train_step(model, TrainConfig(
-                over_decompose=over_decompose,
-                compress_pod_grads=compress),
-                param_axes=axes if compress else None)
+            step = make_train_step(model, TrainConfig())
             jitted = jax.jit(step,
                              in_shardings=(state_spec, batch_shardings),
                              out_shardings=(state_spec, None),
@@ -272,8 +195,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     result: Dict[str, Any] = {
         "arch": arch, "shape": shape_name,
         "mesh": dict(mesh.shape), "chips": n_chips,
-        "opt_level": opt_level, "over_decompose": over_decompose,
-        "seq_shard_kv": seq_shard, "probe": probe,
+        "opt_level": opt_level, "seq_shard_kv": seq_shard,
         "n_layers": cfg.n_layers, "period": len(cfg.layer_pattern),
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
     }
@@ -304,17 +226,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     except Exception as e:  # pragma: no cover
         result["hlo_error"] = str(e)
 
-    # roofline terms (seconds per step, per chip)
     flops = result.get("flops_per_device", 0.0)
-    hbm = result.get("bytes_per_device", 0.0)
-    coll_b = result.get("collective_total_bytes", 0)
-    result["t_compute"] = flops / PEAK_FLOPS if flops > 0 else None
-    result["t_memory"] = hbm / HBM_BW if hbm > 0 else None
-    result["t_collective"] = coll_b / ICI_BW
-    terms = {"compute": result["t_compute"] or 0.0,
-             "memory": result["t_memory"] or 0.0,
-             "collective": result["t_collective"] or 0.0}
-    result["bottleneck"] = max(terms, key=terms.get)
     # model flops: 6·N_active·D(train) / 2·N·D(inference fwd)
     n_active = cfg.active_param_count()
     tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode"
@@ -333,27 +245,10 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--opt-level", default="baseline",
                     choices=["baseline", "opt"])
-    ap.add_argument("--over-decompose", type=int, default=1)
-    ap.add_argument("--probe", type=int, default=None)
-    ap.add_argument("--variant", default=None, choices=sorted(VARIANTS))
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
-    kw: Dict[str, Any] = dict(multi_pod=args.multi_pod,
-                              opt_level=args.opt_level,
-                              over_decompose=args.over_decompose,
-                              probe=args.probe)
-    if args.variant:
-        v = VARIANTS[args.variant]
-        kw["extra_flags"] = v.get("extra_flags")
-        kw["extra_rules"] = v.get("extra_rules")
-        kw["cache_seq_axis"] = v.get("cache_seq_axis")
-        kw["ssd_chunk"] = v.get("ssd_chunk")
-        kw["train_compress"] = v.get("train_compress", False)
-        if "over_decompose" in v:
-            kw["over_decompose"] = v["over_decompose"]
-    res = lower_cell(canon(args.arch), args.shape, **kw)
-    if args.variant:
-        res["variant"] = args.variant
+    res = lower_cell(canon(args.arch), args.shape, multi_pod=args.multi_pod,
+                     opt_level=args.opt_level)
     js = json.dumps(res, indent=2, default=str)
     print(js)
     if args.out:

@@ -62,8 +62,8 @@ def test_dryrun_machinery_smoke():
         "r = lower_cell('olmoe_1b_7b', 'decode_32k')\n"
         "assert r['chips'] == 256, r['chips']\n"
         "assert r.get('flops_per_device', 0) > 0\n"
-        "assert r['bottleneck'] in ('compute', 'memory', 'collective')\n"
-        "print('dryrun ok', r['bottleneck'])\n"
+        "assert r.get('hlo_lines', 0) > 0, r\n"
+        "print('dryrun ok', r['hlo_lines'])\n"
     )
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
