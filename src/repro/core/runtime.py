@@ -53,9 +53,11 @@ mirrored device→host path stages downloads into pooled buffers the same
 way — so host copies never alias device buffers that donation might
 recycle. ``HeteroObject.get(out=...)`` downloads the same pieces straight
 into the caller's array instead, and keeps no host copy
-(``stats()["d2h_direct"]`` counts those). Pool buffers are recycled:
-staging buffers return to the pool when a host copy is dropped, transfer
-futures return to the ``RequestPool`` once consumed.
+(``stats()["d2h_direct"]`` counts those). A host copy may be a strided
+view the caller lends read-only: its upload gathers each piece from the
+strides (``stats()["h2d_strided"]`` counts those). Pool buffers are
+recycled: staging buffers return to the pool when a host copy is dropped,
+transfer futures return to the ``RequestPool`` once consumed.
 
 These configuration fields reproduce the paper's optimization ladder
 (Fig. 8, ``benchmarks/tasking_overhead.py`` sets them both ways):
@@ -248,7 +250,8 @@ class Runtime:
                        "graph_invalidations": 0, "replayed_tasks": 0,
                        "lineage_recomputes": 0, "recompute_depth_peak": 0,
                        "task_retries": 0, "tasks_failed": 0,
-                       "ready_wait_s": 0.0, "d2h_direct": 0}
+                       "ready_wait_s": 0.0, "d2h_direct": 0,
+                       "h2d_strided": 0}
         # lineage ledger: producer records for lost-replica recovery
         self.lineage = LineageLedger()
         self._lineage_lock = sanitizer.make_rlock("Runtime._lineage_lock")
@@ -492,7 +495,9 @@ class Runtime:
             with obj.lock:
                 if write and not arr.flags.writeable:
                     # downloads can be read-only zero-copy views of device
-                    # buffers; a write pin must hand out a writable copy
+                    # buffers, and host values can be read-only views the
+                    # caller lends (run_tasked's chunks of u0); a write pin
+                    # must hand out a writable copy and never write there
                     arr = np.array(arr)
                     obj.copies[HOST] = arr
                     obj._pooled_host = False
@@ -749,6 +754,10 @@ class Runtime:
         import jax.numpy as jnp
         row_bytes = max(1, host_arr.nbytes // host_arr.shape[0])
         rows_per = max(1, chunk // row_bytes)
+        if not host_arr.flags.c_contiguous:
+            # a borrowed strided view: each piece is gathered from its
+            # strides into the staging buffer, with no contiguous copy first
+            self._stats["h2d_strided"] += 1
         pieces, bufs = [], []
         for i in range(0, host_arr.shape[0], rows_per):
             part = host_arr[i:i + rows_per]

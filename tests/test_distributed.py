@@ -313,6 +313,27 @@ def test_jacobi_tasked_matches_reference(od):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("od", [1, 4, 8])
+def test_jacobi_tasked_leaves_the_callers_grid_untouched(od):
+    """Chunks split along axes 1 and 2 borrow strided read-only views of
+    u0; with 128-byte staging pieces every chunk uploads piecewise from its
+    strides, and u0 comes back bitwise as it was."""
+    u0 = np.random.default_rng(5).random((4, 16, 32)).astype(np.float32)
+    before = u0.copy()
+    want = run_reference(u0, 3)
+    cfg = RuntimeConfig(memory_capacity=1 << 26, staging_chunk_bytes=1 << 7)
+    with Runtime(cfg) as rt:
+        plan = plan_decomposition(u0.shape, len(rt.devices), od)
+        assert plan.chunk_grid[0] == 1
+        got = run_tasked(u0, 3, rt, over_decomposition=od)
+        # at least once a chunk: one read on both devices at once uploads
+        # to each
+        assert rt.stats()["h2d_strided"] >= len(plan.chunks)
+    assert u0.flags.writeable
+    np.testing.assert_array_equal(u0, before)
+    np.testing.assert_array_equal(got, want)
+
+
 def test_jacobi_cluster_matches_reference():
     """The distributed Jacobi proxy on the message engine (scatter via
     send, halos via put, gather via send) matches the oracle."""

@@ -184,6 +184,49 @@ def test_chunked_host_upload_through_staging_pool():
         assert rt.staging.hits + rt.staging.misses > 1   # chunked acquires
 
 
+def _lent(grid, layout):
+    """What a caller lends an object: a read-only view of columns 16..63
+    of ``grid`` (strided), or a read-only contiguous copy of them."""
+    view = grid[:, 16:64]
+    if layout == "contiguous":
+        view = np.ascontiguousarray(view)
+    view.flags.writeable = False
+    return view
+
+
+def test_write_request_on_a_lent_view_gets_a_private_copy():
+    grid = np.random.default_rng(1).random((64, 96), dtype=np.float32)
+    before = grid.copy()
+    with Runtime(RuntimeConfig(memory_capacity=1 << 28)) as rt:
+        x = rt.hetero_object(_lent(grid, "strided"))
+        arr = x.request_host(write=True).get(5)
+        assert arr.flags.writeable
+        assert not np.shares_memory(arr, grid)
+        arr[...] = -1.0
+        x.release()
+        np.testing.assert_array_equal(x.get(), -1.0)
+    np.testing.assert_array_equal(grid, before)
+
+
+@pytest.mark.parametrize("layout, strided", [("strided", 1),
+                                             ("contiguous", 0)])
+def test_strided_upload_stages_from_the_strides(layout, strided):
+    # 64 rows of 192 B: 1 KiB pieces are 5 rows, 13 pieces
+    grid = np.random.default_rng(2).random((64, 96), dtype=np.float32)
+    cfg = RuntimeConfig(memory_capacity=1 << 28, staging_chunk_bytes=1 << 10)
+    with Runtime(cfg) as rt:
+        x = rt.hetero_object(_lent(grid, layout))
+        before = rt.stats()
+        on_device = rt._ensure_on_device(x, 0, will_write=False)
+        after = rt.stats()
+        assert after["h2d_strided"] - before["h2d_strided"] == strided
+        assert after["staging_hits"] + after["staging_misses"] - (
+            before["staging_hits"] + before["staging_misses"]) == 13
+        want = rt._device(0).upload(np.ascontiguousarray(grid[:, 16:64]))
+        np.testing.assert_array_equal(np.asarray(on_device),
+                                      np.asarray(want))
+
+
 # ---------------------------------------------------------------------------
 # HeteroObject.get(out=...): download into the caller's buffer
 # ---------------------------------------------------------------------------
